@@ -65,6 +65,8 @@ def main(argv=None) -> None:
     ap.add_argument("--list", action="store_true",
                     help="print discovered suite names and exit")
     args = ap.parse_args(argv)
+    from repro.device import enable_compile_cache
+    enable_compile_cache()
 
     suites = discover_suites()
     if args.list:

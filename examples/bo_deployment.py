@@ -13,6 +13,7 @@ Run:  PYTHONPATH=src python examples/bo_deployment.py --iters 5
 import argparse
 
 from repro.core.runtime import RuntimeConfig, ServerlessMoERuntime
+from repro.device import enable_compile_cache
 
 
 def main() -> None:
@@ -20,6 +21,7 @@ def main() -> None:
     ap.add_argument("--iters", type=int, default=5)
     ap.add_argument("--arch", default="bert-moe")
     args = ap.parse_args()
+    enable_compile_cache()
 
     rc = RuntimeConfig(arch=args.arch, profile_batches=4, learn_batches=1,
                        eval_batches=1, seq_len=64, batch_size=4,

@@ -16,12 +16,14 @@ import numpy as np
 
 from repro.core.predictor import ExpertPredictor
 from repro.core.runtime import RuntimeConfig, ServerlessMoERuntime
+from repro.device import enable_compile_cache
 from repro.plan import DeploymentPlan, Workload
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--smoke", action="store_true",
                 help="reduced smoke mode (CI): tiny dims, fewer batches")
 args = ap.parse_args()
+enable_compile_cache()
 
 if args.smoke:
     rc = RuntimeConfig(arch="gpt2-moe", profile_batches=2, learn_batches=1,

@@ -29,6 +29,7 @@ import numpy as np
 
 from repro.core.runtime import RuntimeConfig, ServerlessMoERuntime
 from repro.core.simulator import FaultProfile
+from repro.device import enable_compile_cache
 from repro.plan import DeploymentPlan, Workload
 from repro.serving import ServingEngine
 
@@ -39,6 +40,7 @@ def main() -> None:
     ap.add_argument("--bo-iters", type=int, default=4)
     ap.add_argument("--arch", default="gpt2-moe")
     args = ap.parse_args()
+    enable_compile_cache()
 
     rc = RuntimeConfig(arch=args.arch, profile_batches=4, learn_batches=1,
                        eval_batches=1, seq_len=64, batch_size=4)

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from repro.checkpoint import save_params
 from repro.config import get_arch, reduced_config
 from repro.data.synthetic import SyntheticCorpus
+from repro.device import enable_compile_cache
 from repro.models import Model
 from repro.optim import adamw_init, adamw_update, cosine_schedule
 
@@ -26,6 +27,7 @@ def main() -> None:
     ap.add_argument("--preset", choices=["tiny", "100m"], default="tiny")
     ap.add_argument("--ckpt", default="")
     args = ap.parse_args()
+    enable_compile_cache()
 
     base = get_arch("gpt2-moe")
     if args.preset == "100m":

@@ -12,7 +12,8 @@ The runtime owns model/corpus/table state and wires the protocols
 together; planning strategies live in ``repro.plan.planner`` and
 execution targets in ``repro.plan.backends``.
 
-Models run at reduced dimensions on CPU (this box has one core); the
+Models run at reduced dimensions (``RuntimeConfig.reduced``, the CPU
+default) or at their published widths (``reduced=False``, on a TPU); the
 ModelProfile scales compute/param/activation quantities back to the FULL
 architecture dims so billed costs are realistic for the paper's models.
 """
@@ -101,26 +102,34 @@ def build_profile(full_cfg: ModelConfig, u_ref_s: float) -> ModelProfile:
 
 def calibrate_u_ref(model: Model, params, cfg: ModelConfig,
                     full_cfg: ModelConfig) -> float:
-    """Time the real (reduced) expert FFN per token and scale by the FLOP
-    ratio to the full architecture, divided by a Lambda-vCPU factor."""
+    """Time the real expert FFN per token and scale by the FLOP ratio to
+    the full architecture, clamped to a Lambda-vCPU range.
+
+    The cost model prices a serverless CPU function, so the FFN runs in
+    float32 on the host CPU device whatever accelerator holds the model:
+    a plan must not depend on whether a chip is attached."""
     from repro.models.moe import expert_ffn
-    moe_p = jax.tree.map(lambda a: a[0], params["blocks"]["pos0"])["moe"]
+    cpu = jax.devices("cpu")[0]
+    moe_p = jax.device_put(
+        jax.tree.map(lambda a: a[0].astype(jnp.float32),
+                     params["blocks"]["pos0"]["moe"]), cpu)
     E = moe_p["router"].shape[-1]
     d = cfg.d_model
     C = 64
-    buf = jnp.ones((E, C, d))
-    fn = jax.jit(lambda b: expert_ffn(moe_p, b, cfg.activation))
-    fn(buf).block_until_ready()
-    t0 = time.perf_counter()
-    reps = 5
-    for _ in range(reps):
+    with jax.default_device(cpu):
+        buf = jnp.ones((E, C, d))
+        fn = jax.jit(lambda b: expert_ffn(moe_p, b, cfg.activation))
         fn(buf).block_until_ready()
-    per_token = (time.perf_counter() - t0) / reps / (E * C)
+        t0 = time.perf_counter()
+        reps = 5
+        for _ in range(reps):
+            fn(buf).block_until_ready()
+        per_token = (time.perf_counter() - t0) / reps / (E * C)
     d_f, ff_f = full_dims(full_cfg)
     m_r = cfg.moe
     assert m_r is not None
     scale = (d_f * ff_f) / max(d * m_r.d_expert_ff, 1)
-    # a Lambda vCPU is ~ this dev box's single core; clamp to sane range
+    # a Lambda vCPU is ~ one host core; clamp to a sane range
     u = float(np.clip(per_token * scale, 1e-5, 1.0))
     return u
 
@@ -157,7 +166,7 @@ class ServerlessMoERuntime:
         self.cfg = cfg
         self.model = Model(cfg)
         key = jax.random.PRNGKey(rc.seed)
-        self.params = self.model.init_params(key)
+        self.params = self.model.init_params(key, jnp.dtype(cfg.dtype))
         # Random-init routers are near-uniform and random-init residual
         # streams lose token identity with depth; trained MoE models keep
         # routing confident and token/position-keyed (paper Fig. 3). Emulate

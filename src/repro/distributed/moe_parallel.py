@@ -40,18 +40,6 @@ from repro.models.moe import (build_dispatch, build_grouped_dispatch,
                               grouped_expert_ffn, route)
 
 
-def _shard_map(f, mesh, in_specs, out_specs):
-    """Version-compat: ``jax.shard_map`` (with ``check_vma``) only exists on
-    newer JAX; 0.4.x ships it at ``jax.experimental.shard_map`` with the
-    replication check spelled ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
-
-
 # β-chunk sizing now lives in the transport-agnostic dispatch substrate
 # (repro.dispatch.chunks) so the shard_map loops here and the process
 # gateway size their chunks identically; the old private name stays as
@@ -155,13 +143,13 @@ def expert_parallel_moe(
     wu = params.get("w_up")
     wd = params.get("w_down", params.get("w_out"))
     shared_p = params.get("shared", {})
-    fn = _shard_map(
-        local_moe, mesh,
+    fn = jax.shard_map(
+        local_moe, mesh=mesh,
         in_specs=(P(), P(model_axis, None, None),
                   P(model_axis, None, None) if wu is not None else P(),
                   P(model_axis, None, None), P(),
                   P(bspec, None, None)),
-        out_specs=(P(bspec, None, None), P()))
+        out_specs=(P(bspec, None, None), P()), check_vma=False)
     return fn(params["router"], wg,
               wu if wu is not None else jnp.zeros(()), wd, shared_p, x)
 
@@ -288,12 +276,12 @@ def expert_parallel_moe_grouped(
     wu = params.get("w_up")
     wd = params.get("w_down", params.get("w_out"))
     shared_p = params.get("shared", {})
-    fn = _shard_map(
-        local_moe, mesh,
+    fn = jax.shard_map(
+        local_moe, mesh=mesh,
         in_specs=(P(), P(model_axis, None, None),
                   P(model_axis, None, None) if wu is not None else P(),
                   P(model_axis, None, None), P(),
                   P(bspec, None, None)),
-        out_specs=(P(bspec, None, None), P()))
+        out_specs=(P(bspec, None, None), P()), check_vma=False)
     return fn(params["router"], wg,
               wu if wu is not None else jnp.zeros(()), wd, shared_p, x)

@@ -2,7 +2,8 @@
 
 Each kernel package ships three modules:
 * ``kernel.py`` -- pl.pallas_call + explicit BlockSpec VMEM tiling (TPU target)
-* ``ops.py``    -- jit'd public wrapper (interpret=True on CPU)
+* ``ops.py``    -- jit'd public wrapper (compiled on a TPU, interpreted
+                    elsewhere: ``repro.device.interpret_kernels``)
 * ``ref.py``    -- pure-jnp oracle used by the allclose tests
 
 Kernels:

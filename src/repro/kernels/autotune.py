@@ -15,8 +15,8 @@ instead:
 2. **Measured pass (optional)** — :func:`tune` times each candidate with
    a caller-supplied closure (see ``benchmarks/kernels_bench.py``) and
    overrides the analytic choice. Interpret-mode wall times measure the
-   Python emulator, so measurement is only meaningful with
-   ``interpret=False`` on a real TPU; the benches use it to produce the
+   Python emulator, so measurement is only meaningful on a real TPU,
+   where the kernels are compiled; the benches use it to produce the
    published tuning tables.
 
 Choices land in a process-level cache and can be persisted/loaded as

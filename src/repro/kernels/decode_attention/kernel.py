@@ -40,14 +40,14 @@ def _decode_kernel(valid_ref, q_ref, k_ref, v_ref, out_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     q = q_ref[0, 0].astype(jnp.float32)            # (G, D)
-    k = k_ref[0, :, 0].astype(jnp.float32)         # (bt, D)
-    v = v_ref[0, :, 0].astype(jnp.float32)         # (bt, D)
+    k = k_ref[0, 0].astype(jnp.float32)            # (bt, D)
+    v = v_ref[0, 0].astype(jnp.float32)            # (bt, D)
     D = q.shape[-1]
     scores = jnp.dot(q, k.T,
                      preferred_element_type=jnp.float32) * (D ** -0.5)
     slot = t * block_t + jax.lax.broadcasted_iota(
         jnp.int32, scores.shape, 1)
-    valid = valid_ref[0]
+    valid = valid_ref[pl.program_id(0)]
     scores = jnp.where(slot < valid, scores, NEG_INF)
 
     m_prev = m_scr[...]                            # (G, 1)
@@ -70,29 +70,37 @@ def _decode_kernel(valid_ref, q_ref, k_ref, v_ref, out_ref,
 
 def decode_attention_kernel(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                             valid_len: jnp.ndarray, *, block_t: int = 512,
-                            interpret: bool = True) -> jnp.ndarray:
-    """q: (B, N, G, D); k, v: (B, T, N, D); valid_len: (B,) int32."""
+                            interpret: bool) -> jnp.ndarray:
+    """q: (B, N, G, D); k, v: head-major (B, N, T, D); valid_len: (B,)
+    int32. Head-major keeps each KV block's last two dims (block_t, D)
+    tileable by Mosaic."""
     B, N, G, D = q.shape
-    T = k.shape[1]
+    T = k.shape[2]
     block_t = min(block_t, T)
     assert T % block_t == 0
-    grid = (B, N, T // block_t)
-    return pl.pallas_call(
-        functools.partial(_decode_kernel, block_t=block_t),
-        grid=grid,
+    # valid_len rides in SMEM by scalar prefetch: index maps and the body
+    # receive the whole (B,) vector
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(B, N, T // block_t),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, t: (b,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, G, D), lambda b, h, t: (b, h, 0, 0)),
-            pl.BlockSpec((1, block_t, 1, D), lambda b, h, t: (b, t, h, 0)),
-            pl.BlockSpec((1, block_t, 1, D), lambda b, h, t: (b, t, h, 0)),
+            pl.BlockSpec((1, 1, G, D), lambda b, h, t, vl: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, block_t, D),
+                         lambda b, h, t, vl: (b, h, t, 0)),
+            pl.BlockSpec((1, 1, block_t, D),
+                         lambda b, h, t, vl: (b, h, t, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, t: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, N, G, D), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, G, D),
+                               lambda b, h, t, vl: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, D), jnp.float32),
         ],
+    )
+    return pl.pallas_call(
+        functools.partial(_decode_kernel, block_t=block_t),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, N, G, D), q.dtype),
         interpret=interpret,
     )(valid_len, q, k, v)

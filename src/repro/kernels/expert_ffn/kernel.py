@@ -67,7 +67,7 @@ def expert_ffn_kernel(buf: jnp.ndarray, w_gate: jnp.ndarray,
                       w_up: Optional[jnp.ndarray], w_down: jnp.ndarray,
                       *, activation: str = "swiglu", block_c: int = 128,
                       block_f: int = 128,
-                      interpret: bool = True) -> jnp.ndarray:
+                      interpret: bool) -> jnp.ndarray:
     E, C, D = buf.shape
     F = w_gate.shape[-1]
     block_c = min(block_c, C)

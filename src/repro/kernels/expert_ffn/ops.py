@@ -1,7 +1,7 @@
 """Public wrapper for the expert FFN kernel.
 
-On this CPU container the kernel body executes under ``interpret=True``;
-on a real TPU pass ``interpret=False`` (the BlockSpecs are TPU-shaped).
+``interpret=None`` (the default) compiles the kernel on a TPU and runs it
+in the Pallas interpreter elsewhere (:func:`repro.device.interpret_kernels`).
 ``block_c=None`` / ``block_f=None`` defer the tile sizes to the
 autotuner (:mod:`repro.kernels.autotune`); explicit values bypass it.
 """
@@ -13,6 +13,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.device import interpret_kernels
 from repro.kernels.autotune import resolve
 from repro.kernels.expert_ffn.kernel import expert_ffn_kernel
 
@@ -61,7 +62,7 @@ def expert_ffn_pallas(buf: jnp.ndarray, w_gate: jnp.ndarray,
                       activation: str = "swiglu",
                       block_c: int | None = None,
                       block_f: int | None = None,
-                      interpret: bool = True) -> jnp.ndarray:
+                      interpret: bool | None = None) -> jnp.ndarray:
     E, C, D = buf.shape
     F = w_gate.shape[-1]
     if block_c is None or block_f is None:
@@ -70,14 +71,13 @@ def expert_ffn_pallas(buf: jnp.ndarray, w_gate: jnp.ndarray,
         block_f = block_f if block_f is not None else knobs["block_f"]
     return _expert_ffn_jit(buf, w_gate, w_up, w_down, activation=activation,
                            block_c=block_c, block_f=block_f,
-                           interpret=interpret)
+                           interpret=interpret_kernels(interpret))
 
 
-def moe_expert_ffn_adapter(params, buf, activation, *, interpret=True):
+def moe_expert_ffn_adapter(params, buf, activation):
     """Drop-in for ``repro.models.moe.expert_ffn`` (same signature)."""
     if activation == "swiglu":
         return expert_ffn_pallas(buf, params["w_gate"], params["w_up"],
-                                 params["w_down"], activation="swiglu",
-                                 interpret=interpret)
+                                 params["w_down"], activation="swiglu")
     return expert_ffn_pallas(buf, params["w_in"], None, params["w_out"],
-                             activation="gelu", interpret=interpret)
+                             activation="gelu")

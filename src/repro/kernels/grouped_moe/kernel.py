@@ -73,7 +73,7 @@ def grouped_moe_kernel(x_sorted: jnp.ndarray, tile_expert: jnp.ndarray,
                        w_gate: jnp.ndarray, w_up, w_down: jnp.ndarray,
                        *, activation: str = "swiglu", block_rows: int = 128,
                        block_f: int = 128,
-                       interpret: bool = True) -> jnp.ndarray:
+                       interpret: bool) -> jnp.ndarray:
     R, D = x_sorted.shape
     E, _, F = w_gate.shape
     assert R % block_rows == 0 and F % block_f == 0, (R, F, block_rows,

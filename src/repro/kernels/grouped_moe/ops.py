@@ -1,7 +1,7 @@
 """Public jit'd wrapper for the dropless ragged grouped-GEMM MoE kernel.
 
-On this CPU container the kernel body executes under ``interpret=True``;
-on a real TPU pass ``interpret=False`` (the BlockSpecs are TPU-shaped).
+``interpret=None`` (the default) compiles the kernel on a TPU and runs it
+in the Pallas interpreter elsewhere (:func:`repro.device.interpret_kernels`).
 """
 from __future__ import annotations
 
@@ -10,6 +10,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.device import interpret_kernels
 from repro.kernels.autotune import resolve
 from repro.kernels.expert_ffn.ops import aligned_block
 from repro.kernels.grouped_moe.kernel import grouped_moe_kernel
@@ -41,7 +42,7 @@ def grouped_moe_pallas(x_sorted: jnp.ndarray, tile_expert: jnp.ndarray,
                        w_gate: jnp.ndarray, w_up, w_down: jnp.ndarray, *,
                        activation: str = "swiglu",
                        block_f: int | None = None,
-                       interpret: bool = True) -> jnp.ndarray:
+                       interpret: bool | None = None) -> jnp.ndarray:
     """x_sorted: (R, D) expert-sorted token rows, each ``R // len(tile_expert)``
     row tile owned by expert ``tile_expert[t]`` (group padding rows are
     zero). Returns the per-row expert FFN output, same shape/dtype.
@@ -53,16 +54,14 @@ def grouped_moe_pallas(x_sorted: jnp.ndarray, tile_expert: jnp.ndarray,
                           rows=R, D=D, F=F)["block_f"]
     return _grouped_moe_jit(x_sorted, tile_expert, w_gate, w_up, w_down,
                             activation=activation, block_f=block_f,
-                            interpret=interpret)
+                            interpret=interpret_kernels(interpret))
 
 
-def moe_grouped_ffn_adapter(params, x_sorted, tile_expert, activation, *,
-                            interpret=True):
+def moe_grouped_ffn_adapter(params, x_sorted, tile_expert, activation):
     """Drop-in for ``repro.models.moe.grouped_expert_ffn`` (same signature)."""
     if activation == "swiglu":
         return grouped_moe_pallas(x_sorted, tile_expert, params["w_gate"],
                                   params["w_up"], params["w_down"],
-                                  activation="swiglu", interpret=interpret)
+                                  activation="swiglu")
     return grouped_moe_pallas(x_sorted, tile_expert, params["w_in"], None,
-                              params["w_out"], activation="gelu",
-                              interpret=interpret)
+                              params["w_out"], activation="gelu")

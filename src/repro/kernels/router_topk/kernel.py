@@ -39,7 +39,7 @@ def _tile_topk(x_ref, w_ref, *, k: int, valid_experts: int,
     """Shared per-tile routing math.
 
     Returns (probs (bn, E) with padded rows zeroed, vals (bn, k)
-    normalized, idx (bn, k) i32, live_row (bn, 1) bool, logsumexp (bn,)).
+    normalized, idx (bn, k) i32, live_row (bn, 1) bool, logsumexp (bn, 1)).
     """
     n = pl.program_id(0)
     x = x_ref[...].astype(jnp.float32)            # (bn, D)
@@ -57,7 +57,7 @@ def _tile_topk(x_ref, w_ref, *, k: int, valid_experts: int,
     row = n * block_n + jax.lax.broadcasted_iota(jnp.int32, (bn, 1), 0)
     live_row = row < valid_rows                                 # (bn, 1)
     probs = jnp.where(live_row, probs, 0.0)
-    lse = (m + jnp.log(psum))[:, 0]                             # (bn,)
+    lse = m + jnp.log(psum)                                     # (bn, 1)
 
     work = probs
     vals = []
@@ -107,23 +107,31 @@ def _router_fused_kernel(x_ref, w_ref, vals_ref, idx_ref, pos_ref,
         counts_ref[...] = jnp.zeros_like(counts_ref)
         stats_ref[...] = jnp.zeros_like(stats_ref)
 
-    # stable within-expert rank: exclusive cumsum of the one-hot routed
-    # pairs in flattened row-major (token, k) order — bit-equal to the
-    # rank a stable argsort-by-expert assigns in build_dispatch
-    pair_e = idx.reshape(bn * k)
-    colE = jax.lax.broadcasted_iota(jnp.int32, (bn * k, E), 1)
-    live_pair = jnp.broadcast_to(live_row, (bn, k)).reshape(bn * k, 1)
-    oh = jnp.where((colE == pair_e[:, None]) & live_pair, 1, 0)
-    csum = jnp.cumsum(oh, axis=0)
+    # stable within-expert rank in flattened row-major (token, k) order —
+    # bit-equal to the rank a stable argsort-by-expert assigns in
+    # build_dispatch. A token's k experts are distinct, so a pair's rank
+    # is the number of earlier live tokens routed to its expert: an
+    # exclusive prefix sum over the tile's tokens, taken as a strictly
+    # lower-triangular matmul (Mosaic has no cumsum). Operands are 0/1
+    # and accumulation is f32, so the counts are exact.
+    colE = jax.lax.broadcasted_iota(jnp.int32, (bn, E), 1)
+    hits = [jnp.where((colE == idx[:, j:j + 1]) & live_row, 1.0, 0.0)
+            for j in range(k)]                                  # k x (bn, E)
+    routed = functools.reduce(jnp.add, hits)                    # (bn, E) 0/1
+    row_i = jax.lax.broadcasted_iota(jnp.int32, (bn, bn), 0)
+    col_i = jax.lax.broadcasted_iota(jnp.int32, (bn, bn), 1)
+    earlier = jnp.where(col_i < row_i, 1.0, 0.0)
+    excl = jnp.dot(earlier, routed, preferred_element_type=jnp.float32)
     base = counts_ref[0, :]                                     # (E,)
-    rank = (csum - oh) + base[None, :]
-    pos_ref[...] = (rank * oh).sum(-1).reshape(bn, k)
-    counts_ref[0, :] = base + oh.sum(0)
+    rank = excl.astype(jnp.int32) + base[None, :]
+    pos_ref[...] = jnp.stack(
+        [(rank * h.astype(jnp.int32)).sum(-1) for h in hits], axis=-1)
+    counts_ref[0, :] = base + routed.sum(0).astype(jnp.int32)
 
     # router-loss sufficient statistics: per-expert prob mass and
     # sum(logsumexp^2) over live rows (z broadcast across the row so the
     # wrapper can read element [1, 0])
-    z_blk = jnp.sum(jnp.where(live_row[:, 0], lse * lse, 0.0))
+    z_blk = jnp.sum(jnp.where(live_row, lse * lse, 0.0))
     stats_ref[0, :] = stats_ref[0, :] + probs.sum(0)
     stats_ref[1, :] = stats_ref[1, :] + z_blk
 
@@ -131,7 +139,7 @@ def _router_fused_kernel(x_ref, w_ref, vals_ref, idx_ref, pos_ref,
 def router_topk_kernel(x: jnp.ndarray, router_w: jnp.ndarray, *, k: int,
                        valid_experts: int, block_n: int = 256,
                        valid_rows: int | None = None,
-                       interpret: bool = True):
+                       interpret: bool):
     N, D = x.shape
     E = router_w.shape[-1]
     block_n = min(block_n, N)
@@ -155,7 +163,7 @@ def router_topk_kernel(x: jnp.ndarray, router_w: jnp.ndarray, *, k: int,
 def router_topk_fused_kernel(x: jnp.ndarray, router_w: jnp.ndarray, *,
                              k: int, valid_experts: int, block_n: int = 256,
                              valid_rows: int | None = None,
-                             interpret: bool = True):
+                             interpret: bool):
     """Routing + dispatch metadata in one pass.
 
     Returns ``(vals (N, k) f32, idx (N, k) i32, pos_in_e (N, k) i32,

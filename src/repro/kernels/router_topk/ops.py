@@ -15,6 +15,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.device import interpret_kernels
 from repro.kernels.autotune import resolve
 from repro.kernels.router_topk.kernel import (router_topk_fused_kernel,
                                               router_topk_kernel)
@@ -50,7 +51,8 @@ def _router_topk_fused_jit(x, router_w, *, k, valid_experts, block_n,
 
 def router_topk_pallas(x: jnp.ndarray, router_w: jnp.ndarray, *, k: int,
                        valid_experts: int | None = None,
-                       block_n: int | None = None, interpret: bool = True
+                       block_n: int | None = None,
+                       interpret: bool | None = None
                        ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Router gating: returns (normalized top-k weights, expert indices).
 
@@ -66,13 +68,13 @@ def router_topk_pallas(x: jnp.ndarray, router_w: jnp.ndarray, *, k: int,
                           N=N, D=D, E=E, k=k)["block_n"]
     bn = min(block_n, N)
     return _router_topk_jit(x, router_w, k=k, valid_experts=ve, block_n=bn,
-                            interpret=interpret)
+                            interpret=interpret_kernels(interpret))
 
 
 def router_topk_fused_pallas(x: jnp.ndarray, router_w: jnp.ndarray, *,
                              k: int, valid_experts: int | None = None,
                              block_n: int | None = None,
-                             interpret: bool = True):
+                             interpret: bool | None = None):
     """One-pass routing + dispatch metadata.
 
     Returns ``(vals (N, k) f32, idx (N, k) i32, pos_in_e (N, k) i32,
@@ -94,4 +96,5 @@ def router_topk_fused_pallas(x: jnp.ndarray, router_w: jnp.ndarray, *,
                           N=N, D=D, E=E, k=k)["block_n"]
     bn = min(block_n, N)
     return _router_topk_fused_jit(x, router_w, k=k, valid_experts=ve,
-                                  block_n=bn, interpret=interpret)
+                                  block_n=bn,
+                                  interpret=interpret_kernels(interpret))

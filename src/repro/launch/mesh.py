@@ -6,18 +6,7 @@ touches jax device state (device count locks on first jax init).
 from __future__ import annotations
 
 import jax
-
-
-def _mesh_kwargs(n_axes: int) -> dict:
-    """``axis_types`` only exists on newer JAX (>= 0.5); omit it otherwise.
-
-    On older releases every mesh axis is implicitly Auto, which is exactly
-    what we request on newer ones, so behavior is identical either way.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -25,10 +14,10 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: (2, 16, 16) pod x data x model = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_debug_mesh(model_size: int = 1):
     """1-device mesh for CPU tests of the sharded code paths."""
     return jax.make_mesh((1, model_size), ("data", "model"),
-                         **_mesh_kwargs(2))
+                         axis_types=(AxisType.Auto,) * 2)

@@ -275,8 +275,8 @@ def attention_decode_step(
     ``backend`` selects the attention realization: ``"jnp"`` (dense
     einsum under ``dense_threshold``, blocked flash above) or
     ``"pallas"`` — ``repro.kernels.decode_attention`` with per-row
-    ``valid_len`` (interpret-mode on CPU; falls back to dense when
-    ``capture`` needs the score matrix).
+    ``valid_len`` (compiled on a TPU, interpreted elsewhere). The kernel
+    emits no score matrix, so it refuses ``capture``.
 
     Windowed layers use a rolling cache of ``window`` slots (write at
     ``pos % window``); full layers write at ``pos``. Cross-attention reads a
@@ -336,7 +336,10 @@ def attention_decode_step(
     kt = jnp.moveaxis(k_att, 1, 2)
     vt = jnp.moveaxis(v_att, 1, 2)
     attn_argmax = None
-    if backend == "pallas" and not capture:
+    if backend == "pallas":
+        if capture:
+            raise ValueError("the flash-decode kernel emits no attention "
+                             "argmax; capture needs attn_backend='jnp'")
         from repro.kernels.decode_attention.ops import decode_attention_pallas
         out = decode_attention_pallas(qg[:, :, :, 0, :], k_att, v_att,
                                       valid)[:, :, :, None, :]

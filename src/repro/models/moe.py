@@ -57,8 +57,8 @@ MOE_EXECUTORS = ("dense", "grouped", "oracle")
 #                  oracle.
 #   "pallas"    -- repro.kernels.router_topk.router_topk_fused_pallas:
 #                  the matmul+softmax+top-k+rank+counts kernel
-#                  (interpret-mode on CPU; tolerance-pinned, integers
-#                  exact).
+#                  (compiled on a TPU, interpreted elsewhere;
+#                  tolerance-pinned, integers exact).
 ROUTER_IMPLS = ("fused", "reference", "pallas")
 
 
@@ -174,9 +174,10 @@ def route_fused(router_w: jnp.ndarray, x_flat: jnp.ndarray, m: MoEConfig,
 
 
 def route_fused_pallas(router_w: jnp.ndarray, x_flat: jnp.ndarray,
-                       m: MoEConfig, valid_experts: Optional[int] = None,
-                       *, interpret: bool = True) -> FusedRouting:
-    """Fused routing via the Pallas kernel (interpret-mode on CPU).
+                       m: MoEConfig, valid_experts: Optional[int] = None
+                       ) -> FusedRouting:
+    """Fused routing via the Pallas kernel (compiled on a TPU,
+    interpreted elsewhere).
 
     Integer outputs (indices, ranks, counts) are exact; weights and the
     losses are tolerance-pinned against :func:`route_fused` (the kernel
@@ -187,8 +188,7 @@ def route_fused_pallas(router_w: jnp.ndarray, x_flat: jnp.ndarray,
     E = router_w.shape[-1]
     N = x_flat.shape[0]
     vals, idx, pos, counts, probs_sum, z_sq = router_topk_fused_pallas(
-        x_flat, router_w, k=m.top_k, valid_experts=valid_experts,
-        interpret=interpret)
+        x_flat, router_w, k=m.top_k, valid_experts=valid_experts)
     ohot = jax.nn.one_hot(idx[:, 0], E)
     lb = E * jnp.sum(ohot.mean(axis=0) * (probs_sum / N))
     z = z_sq / N

@@ -54,7 +54,8 @@ from repro.serving.telemetry import ExpertTelemetry
 # attention (batched decode attends only over the longest LIVE slot,
 # bucketed, instead of the full max_len buffer). "pallas" additionally
 # routes MoE gating through the fused Pallas router kernel and decode
-# attention through the flash-decode Pallas kernel. "reference" is the
+# attention through the flash-decode Pallas kernel (which emits no
+# attention argmax, so it runs without telemetry). "reference" is the
 # original separate-pass / full-buffer path, kept as the equivalence
 # baseline.
 ENGINE_KERNELS = ("fused", "pallas", "reference")
@@ -107,6 +108,11 @@ class ServingEngine:
                             self.cfg.vocab_size, len(self.cfg.pattern))
             if collect_telemetry and moe is not None else None)
         self._capture = self.telemetry is not None
+        if kernels == "pallas" and self._capture:
+            raise ValueError(
+                "kernels='pallas' decodes through the flash-decode kernel, "
+                "which emits no attention argmax for telemetry; pass "
+                "collect_telemetry=False")
         # speculative dispatch: an OnlinePredictor emitting per-layer
         # prewarm hints ahead of each decode step, learning online from
         # the telemetry records the step produces

@@ -21,6 +21,7 @@ from hypothesis import strategies as st
 
 from repro.config import get_arch, reduced_config
 from repro.models import Model
+from repro.models.mlp import mlp_forward
 from repro.models.moe import (_all_experts_out, moe_forward,
                               moe_forward_oracle, route)
 
@@ -154,7 +155,12 @@ def test_dense_matches_oracle_on_non_dropped_pairs(n, alpha, seed):
     sel = jnp.take_along_axis(jnp.moveaxis(all_out, 0, 1),
                               r.topk_idx[..., None], axis=1)    # (N, k, d)
     w = jnp.where(jnp.asarray(s.drop_mask), 0.0, r.topk_weight)
-    y_manual = jnp.einsum("nkd,nk->nd", sel, w).reshape(x.shape)
+    y_manual = jnp.einsum("nkd,nk->nd", sel, w)
+    if m.num_shared_experts > 0:
+        # shared experts see every token, dropped or not
+        y_manual = y_manual + mlp_forward(moe_p["shared"], x_flat,
+                                          cfg.activation)
+    y_manual = y_manual.reshape(x.shape)
     np.testing.assert_allclose(np.asarray(y_dense), np.asarray(y_manual),
                                rtol=2e-5, atol=2e-5)
     # drop ledger consistency: mask counts == per-expert dropped counts

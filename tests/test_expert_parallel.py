@@ -4,6 +4,7 @@ Runs in a SUBPROCESS with 8 forced host devices (the main test process must
 keep the single real CPU device — see conftest note), asserting that the
 all_to_all scatter/gather path reproduces the local dense-dispatch MoE.
 """
+import os
 import subprocess
 import sys
 from pathlib import Path
@@ -22,8 +23,8 @@ from repro.distributed.moe_parallel import expert_parallel_moe
 cfg = reduced_config(get_arch("qwen2-moe-a2.7b"))
 cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
     cfg.moe, capacity_factor=8.0))
-from repro.launch.mesh import _mesh_kwargs
-mesh = jax.make_mesh((2, 4), ("data", "model"), **_mesh_kwargs(2))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 model = Model(cfg, expert_pad_multiple=4)
 params = model.init_params(jax.random.PRNGKey(0))
 moe_p = jax.tree.map(lambda a: a[0], params["blocks"]["pos0"])["moe"]
@@ -65,8 +66,8 @@ print("ALL OK")
 def test_expert_parallel_matches_local():
     res = subprocess.run(
         [sys.executable, "-c", SCRIPT],
-        env={"PYTHONPATH": str(Path(__file__).parent.parent / "src"),
-             "PATH": "/usr/bin:/bin",
-             "HOME": "/root"},
+        env={**os.environ,
+             "PYTHONPATH": str(Path(__file__).parent.parent / "src"),
+             "JAX_PLATFORMS": "cpu"},
         capture_output=True, text=True, timeout=560)
     assert "ALL OK" in res.stdout, res.stdout + "\n" + res.stderr[-3000:]

@@ -435,11 +435,15 @@ def test_kv_len_bucket_is_output_invariant(gpt2_moe):
     assert outs[0] == outs[1] == outs[2]
 
 
-def test_unknown_engine_kernels_rejected(gpt2_moe):
+@pytest.mark.parametrize("kernels,telemetry", [("turbo", False),
+                                               ("pallas", True)])
+def test_unknown_engine_kernels_rejected(gpt2_moe, kernels, telemetry):
+    """An unknown kernel path is refused, and so is the flash-decode path
+    with telemetry on: the kernel emits no attention argmax to capture."""
     cfg, model, params = gpt2_moe
     with pytest.raises(ValueError, match="kernels"):
         ServingEngine(model, params, max_len=32, batch_size=1,
-                      collect_telemetry=False, kernels="turbo")
+                      collect_telemetry=telemetry, kernels=kernels)
 
 
 # ------------------------------------------------------------- prefix cache

@@ -512,9 +512,10 @@ class TestPlanFnSniffing:
         assert _plan_fn_extra_kw(outer, 0.1, None) == {"delta": 0.1}
 
     def test_unsniffable_callable_degrades_to_empty(self):
-        # np.add is a C ufunc: inspect.signature raises; the partial
-        # wrapper used to make the sniff crash or mis-forward
-        assert _plan_fn_extra_kw(functools.partial(np.add, 3),
+        # max is a C builtin with several call forms, so it carries no
+        # text signature and inspect.signature raises; the partial wrapper
+        # used to make the sniff crash or mis-forward
+        assert _plan_fn_extra_kw(functools.partial(max, 3),
                                  0.1, 1.0) == {}
 
     def test_no_request_no_sniff(self):
