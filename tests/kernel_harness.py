@@ -3,7 +3,7 @@
 One declarative case table covers EVERY Pallas kernel in
 ``repro.kernels`` (``router_topk``, ``expert_ffn``, ``decode_attention``,
 ``grouped_moe``): each :class:`KernelCase` builds pinned-seed inputs,
-runs the jit'd Pallas wrapper (``interpret=True`` on CPU) and its
+runs the jit'd Pallas wrapper (interpreted off a TPU) and its
 ``ref.py`` oracle, and compares under ONE parameterized tolerance table
 (dtype x comparison kind). ``tests/test_kernel_oracles.py`` materializes
 the grid; benchmarks reuse ``run_case`` for their parity checks.
