@@ -278,6 +278,8 @@ def attention_decode_step(
     ``valid_len`` (compiled on a TPU, interpreted elsewhere). The kernel
     emits no score matrix, so it refuses ``capture``.
 
+    The cache update runs under the name scope ``kv_write``.
+
     Windowed layers use a rolling cache of ``window`` slots (write at
     ``pos % window``); full layers write at ``pos``. Cross-attention reads a
     static cache (encoder K/V, ``valid_len`` masks encoder padding) and
@@ -315,15 +317,16 @@ def attention_decode_step(
         if rope_theta > 0:
             knew = apply_rope(knew, rope_pos, inv)
         slot = pos % T if window > 0 else pos
-        if per_slot:
-            rows = jnp.arange(B)
-            k = cache["k"].at[rows, slot].set(knew[:, 0], mode="drop")
-            v = cache["v"].at[rows, slot].set(vnew[:, 0], mode="drop")
-        else:
-            k = jax.lax.dynamic_update_slice(cache["k"], knew,
-                                             (0, slot, 0, 0))
-            v = jax.lax.dynamic_update_slice(cache["v"], vnew,
-                                             (0, slot, 0, 0))
+        with jax.named_scope("kv_write"):
+            if per_slot:
+                rows = jnp.arange(B)
+                k = cache["k"].at[rows, slot].set(knew[:, 0], mode="drop")
+                v = cache["v"].at[rows, slot].set(vnew[:, 0], mode="drop")
+            else:
+                k = jax.lax.dynamic_update_slice(cache["k"], knew,
+                                                 (0, slot, 0, 0))
+                v = jax.lax.dynamic_update_slice(cache["v"], vnew,
+                                                 (0, slot, 0, 0))
         valid = jnp.minimum(pos + 1, T) if window > 0 else pos + 1
         new_cache = {"k": k, "v": v}
 

@@ -4,6 +4,10 @@ A block = sequence mixer + optional feed-forward, selected by
 :class:`repro.config.LayerSpec`. Zamba-style ``shared_attn`` blocks read
 their mixer (and companion FFN) weights from a single globally shared
 parameter set passed separately, so scanning over blocks never stacks them.
+
+The attention mixer runs under the name scope ``attention`` and the MoE
+feed-forward under ``moe`` (``jax.named_scope``: the compiled ops'
+``op_name`` carries them into the profiler's trace).
 """
 from __future__ import annotations
 
@@ -125,9 +129,10 @@ def block_forward(
         attn_p = shared["attn"] if spec.mixer == "shared_attn" else params["attn"]
         window = cfg.sliding_window if spec.mixer == "swa" else 0
         rope = cfg.rope_theta if cfg.pos_embed == "rope" else 0.0
-        y, kv, argmax = attention_forward(
-            attn_p, cfg, h, positions=positions, causal=cfg.causal,
-            window=window, rope_theta=rope, capture=capture)
+        with jax.named_scope("attention"):
+            y, kv, argmax = attention_forward(
+                attn_p, cfg, h, positions=positions, causal=cfg.causal,
+                window=window, rope_theta=rope, capture=capture)
         if return_cache:
             cache["attn"] = kv
         if capture and argmax is not None:
@@ -162,14 +167,15 @@ def block_forward(
         x = x + mlp_forward(mlp_p, h, cfg.activation)
     elif spec.ffn == "moe":
         h = apply_norm(cfg.norm, params["norm2"], x)
-        if moe_layer_fn is not None:    # e.g. expert-parallel shard_map
-            y, aux = moe_layer_fn(params["moe"], cfg, h)
-        else:
-            y, aux = moe_forward(params["moe"], cfg, h, capture=capture,
-                                 executor=moe_executor,
-                                 expert_ffn_fn=moe_ffn_fn,
-                                 grouped_ffn_fn=moe_grouped_fn,
-                                 router_impl=moe_router_impl)
+        with jax.named_scope("moe"):
+            if moe_layer_fn is not None:    # e.g. expert-parallel shard_map
+                y, aux = moe_layer_fn(params["moe"], cfg, h)
+            else:
+                y, aux = moe_forward(params["moe"], cfg, h, capture=capture,
+                                     executor=moe_executor,
+                                     expert_ffn_fn=moe_ffn_fn,
+                                     grouped_ffn_fn=moe_grouped_fn,
+                                     router_impl=moe_router_impl)
         x = x + y
         cap["lb_loss"] = aux["lb_loss"]
         cap["z_loss"] = aux["z_loss"]
@@ -224,11 +230,12 @@ def block_decode_step(
         attn_p = shared["attn"] if spec.mixer == "shared_attn" else params["attn"]
         window = cfg.sliding_window if spec.mixer == "swa" else 0
         rope = cfg.rope_theta if cfg.pos_embed == "rope" else 0.0
-        y, kv, argmax = attention_decode_step(
-            attn_p, cfg, h, cache["attn"], pos=pos, causal=cfg.causal,
-            window=window, rope_theta=rope, capture=capture,
-            dense_threshold=dense_threshold, kv_len=kv_len,
-            backend=attn_backend)
+        with jax.named_scope("attention"):
+            y, kv, argmax = attention_decode_step(
+                attn_p, cfg, h, cache["attn"], pos=pos, causal=cfg.causal,
+                window=window, rope_theta=rope, capture=capture,
+                dense_threshold=dense_threshold, kv_len=kv_len,
+                backend=attn_backend)
         new_cache["attn"] = kv
         if capture and argmax is not None:
             cap["attn_argmax"] = argmax
@@ -262,14 +269,15 @@ def block_decode_step(
         x = x + mlp_forward(mlp_p, h, cfg.activation)
     elif spec.ffn == "moe":
         h = apply_norm(cfg.norm, params["norm2"], x)
-        if moe_layer_fn is not None:
-            y, aux = moe_layer_fn(params["moe"], cfg, h)
-        else:
-            y, aux = moe_forward(params["moe"], cfg, h, capture=capture,
-                                 executor=moe_executor,
-                                 expert_ffn_fn=moe_ffn_fn,
-                                 grouped_ffn_fn=moe_grouped_fn,
-                                 router_impl=moe_router_impl)
+        with jax.named_scope("moe"):
+            if moe_layer_fn is not None:
+                y, aux = moe_layer_fn(params["moe"], cfg, h)
+            else:
+                y, aux = moe_forward(params["moe"], cfg, h, capture=capture,
+                                     executor=moe_executor,
+                                     expert_ffn_fn=moe_ffn_fn,
+                                     grouped_ffn_fn=moe_grouped_fn,
+                                     router_impl=moe_router_impl)
         x = x + y
         if capture and "topk_idx" in aux:
             cap["topk_idx"] = aux["topk_idx"]

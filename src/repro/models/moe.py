@@ -18,6 +18,11 @@ executors, selected by ``moe_forward(..., executor=...)``:
 * ``"oracle"``  -- every expert computed for every token, top-k mixed
   (O(N*E*ff), tests/benchmarks only).
 
+The dense and grouped executors run their stages under the name scopes
+``router``, ``dispatch``, ``experts`` (shared experts too) and
+``combine`` (``jax.named_scope``), which the profiler's trace carries in
+each op's ``op_name``.
+
 Every executor emits a shared :class:`RoutingSummary` (per-expert routed
 /kept/dropped counts, drop mask, group offsets) consumed by the serving
 telemetry, so downstream cost measurements see exactly what the execution
@@ -535,25 +540,30 @@ def moe_forward(params: Params, cfg: ModelConfig, x: jnp.ndarray,
                          f"expected one of {ROUTER_IMPLS}")
     B, S, d = x.shape
     x_flat = x.reshape(B * S, d)
-    if router_impl == "reference":
-        r = route(params["router"], x_flat, m, valid_experts=m.num_experts)
-        fr = None
-    elif router_impl == "pallas":
-        r = fr = route_fused_pallas(params["router"], x_flat, m,
-                                    valid_experts=m.num_experts)
-    else:
-        r = fr = route_fused(params["router"], x_flat, m,
-                             valid_experts=m.num_experts)
+    with jax.named_scope("router"):
+        if router_impl == "reference":
+            r = route(params["router"], x_flat, m,
+                      valid_experts=m.num_experts)
+            fr = None
+        elif router_impl == "pallas":
+            r = fr = route_fused_pallas(params["router"], x_flat, m,
+                                        valid_experts=m.num_experts)
+        else:
+            r = fr = route_fused(params["router"], x_flat, m,
+                                 valid_experts=m.num_experts)
     E = params["router"].shape[-1]
 
     if executor == "dense":
         C = capacity_for(B * S, m, E)
-        plan = (build_dispatch(r.topk_idx, E, C) if fr is None
-                else dispatch_plan_from_fused(fr, E, C))
-        buf = dispatch_tokens(x_flat, plan, E)
-        fn = expert_ffn_fn or expert_ffn
-        buf_out = fn(params, buf, cfg.activation)
-        y = combine_tokens(buf_out, plan, r.topk_weight)
+        with jax.named_scope("dispatch"):
+            plan = (build_dispatch(r.topk_idx, E, C) if fr is None
+                    else dispatch_plan_from_fused(fr, E, C))
+            buf = dispatch_tokens(x_flat, plan, E)
+        with jax.named_scope("experts"):
+            fn = expert_ffn_fn or expert_ffn
+            buf_out = fn(params, buf, cfg.activation)
+        with jax.named_scope("combine"):
+            y = combine_tokens(buf_out, plan, r.topk_weight)
         counts = plan.expert_counts
         kept = jnp.minimum(counts, C)    # sort-based: first C per expert
         summary = RoutingSummary(
@@ -565,13 +575,17 @@ def moe_forward(params: Params, cfg: ModelConfig, x: jnp.ndarray,
             capacity=jnp.int32(C),
         )
     elif executor == "grouped":
-        gd = (build_grouped_dispatch(r.topk_idx, E, block_rows=block_rows)
-              if fr is None else
-              grouped_dispatch_from_fused(fr, E, block_rows=block_rows))
-        buf = dispatch_grouped(x_flat, gd)
-        fn = grouped_ffn_fn or grouped_expert_ffn
-        buf_out = fn(params, buf, gd.tile_expert, cfg.activation)
-        y = combine_grouped(buf_out, gd, r.topk_weight)
+        with jax.named_scope("dispatch"):
+            gd = (build_grouped_dispatch(r.topk_idx, E,
+                                         block_rows=block_rows)
+                  if fr is None else
+                  grouped_dispatch_from_fused(fr, E, block_rows=block_rows))
+            buf = dispatch_grouped(x_flat, gd)
+        with jax.named_scope("experts"):
+            fn = grouped_ffn_fn or grouped_expert_ffn
+            buf_out = fn(params, buf, gd.tile_expert, cfg.activation)
+        with jax.named_scope("combine"):
+            y = combine_grouped(buf_out, gd, r.topk_weight)
         summary = _dropless_summary(gd.expert_counts,
                                     (B * S, m.top_k), gd.group_offsets)
     else:  # oracle
@@ -586,7 +600,8 @@ def moe_forward(params: Params, cfg: ModelConfig, x: jnp.ndarray,
                                     jnp.cumsum(counts) - counts)
 
     if m.num_shared_experts > 0:
-        y = y + mlp_forward(params["shared"], x_flat, cfg.activation)
+        with jax.named_scope("experts"):
+            y = y + mlp_forward(params["shared"], x_flat, cfg.activation)
     aux: Dict[str, jnp.ndarray] = {
         "lb_loss": r.lb_loss * m.router_aux_coef,
         "z_loss": r.z_loss * m.router_z_coef,

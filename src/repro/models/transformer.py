@@ -9,6 +9,11 @@ Supports every assigned architecture family:
 * multimodal stubs: frontend embeddings prepended (VLM) or fed to the
   encoder (audio).
 
+Prefill and decode run the embedding under the name scope ``embed`` and
+the final norm and LM head under ``head`` (``jax.named_scope``; the
+blocks add ``attention`` and ``moe``), so the profiler's trace can put
+each device op down to the part of the model it belongs to.
+
 Params are plain nested dicts. Block params are stacked along a leading
 ``num_blocks`` axis; zamba-style shared weights live under ``params["shared"]``
 and are closed over (never stacked).
@@ -157,15 +162,16 @@ class Model:
         cfg = self.cfg
         executor = moe_executor or self.moe_executor
         router_impl = moe_router_impl or self.moe_router_impl
-        x = jnp.take(params["embed"], tokens, axis=0)
-        n_front = 0
-        if cfg.frontend == "vision_stub" and frontend is not None:
-            x = jnp.concatenate([frontend.astype(x.dtype), x], axis=1)
-            n_front = frontend.shape[1]
-        S = x.shape[1]
-        positions = jnp.arange(S)
-        if cfg.pos_embed == "learned":
-            x = x + params["pos_table"][:S]
+        with jax.named_scope("embed"):
+            x = jnp.take(params["embed"], tokens, axis=0)
+            n_front = 0
+            if cfg.frontend == "vision_stub" and frontend is not None:
+                x = jnp.concatenate([frontend.astype(x.dtype), x], axis=1)
+                n_front = frontend.shape[1]
+            S = x.shape[1]
+            positions = jnp.arange(S)
+            if cfg.pos_embed == "learned":
+                x = x + params["pos_table"][:S]
 
         enc_out = None
         if cfg.is_encoder_decoder:
@@ -193,7 +199,8 @@ class Model:
         if self.remat and not (capture or return_cache):
             body = jax.checkpoint(body)   # activation remat per block
         x, (cache, caps) = jax.lax.scan(body, x, params["blocks"])
-        x = apply_norm(cfg.norm, params["final_norm"], x)
+        with jax.named_scope("head"):
+            x = apply_norm(cfg.norm, params["final_norm"], x)
 
         aux: Dict[str, Any] = {"n_front": n_front}
         lb = z = 0.0
@@ -211,7 +218,8 @@ class Model:
             aux["captures"] = caps
         if hidden_only:
             return x, aux, (cache if return_cache else None)
-        logits = x @ self.head_weight(params)
+        with jax.named_scope("head"):
+            logits = x @ self.head_weight(params)
         return logits, aux, (cache if return_cache else None)
 
     def head_weight(self, params: Params) -> jnp.ndarray:
@@ -322,13 +330,15 @@ class Model:
         router_impl = moe_router_impl or self.moe_router_impl
         backend = attn_backend or self.attn_backend
         pos = jnp.asarray(pos)
-        x = jnp.take(params["embed"], tokens, axis=0)
-        if cfg.pos_embed == "learned":
-            if pos.ndim == 1:      # per-slot positions: (B,) -> (B, 1, d)
-                x = x + jnp.take(params["pos_table"], pos, axis=0)[:, None]
-            else:
-                x = x + jax.lax.dynamic_slice_in_dim(params["pos_table"],
-                                                     pos, 1, axis=0)
+        with jax.named_scope("embed"):
+            x = jnp.take(params["embed"], tokens, axis=0)
+            if cfg.pos_embed == "learned":
+                if pos.ndim == 1:  # per-slot positions: (B,) -> (B, 1, d)
+                    x = x + jnp.take(params["pos_table"], pos,
+                                     axis=0)[:, None]
+                else:
+                    x = x + jax.lax.dynamic_slice_in_dim(
+                        params["pos_table"], pos, 1, axis=0)
         shared = params["shared"]
 
         def body(h, xs):
@@ -352,9 +362,10 @@ class Model:
 
         x, (new_cache, caps) = jax.lax.scan(body, x,
                                             (params["blocks"], cache))
-        x = apply_norm(cfg.norm, params["final_norm"], x)
-        logits = x @ (params["embed"].T if cfg.tie_embeddings
-                      else params["lm_head"])
+        with jax.named_scope("head"):
+            x = apply_norm(cfg.norm, params["final_norm"], x)
+            logits = x @ (params["embed"].T if cfg.tie_embeddings
+                          else params["lm_head"])
         if capture:
             return logits, new_cache, caps
         return logits, new_cache

@@ -31,6 +31,23 @@ actually happened (hits/misses into :class:`ExpertTelemetry`) and the
 step's observations stream back into the predictor — the online
 predict -> prewarm -> measure loop of the paper's §III-B, closed at
 serving granularity.
+
+Every step writes host spans into the profiler's trace (``jax.profiler``
+annotations, a flag check each while no profiler runs), on the same
+clock as the device's programs, so that an idle gap on the device can be
+put down to what the host was doing: ``serving.step`` around a whole
+step (``step_num``, ``live`` slots, the ``kv_len`` rows read),
+``serving.admit`` around one admission (``uid``, ``prompt_len``,
+``kind`` of prefix-cache lookup) holding ``serving.prefill``, inside
+which ``serving.kv_insert`` and ``serving.device_wait`` nest;
+``serving.decode`` around building a decode step's inputs and
+dispatching it; ``serving.device_wait`` where the host first waits for a
+program's outputs; ``serving.telemetry`` around the captures' copy to
+the host and the telemetry, predictor and cache hooks; and
+``serving.sample`` around the logits' copy, the argmax and the per-slot
+update. ``kv_rows_read`` and ``kv_rows_live`` count the cache rows the
+decode steps read (every slot up to ``kv_len``) and those of them that
+live requests hold.
 """
 from __future__ import annotations
 
@@ -40,6 +57,7 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.dispatch.rounds import RoundAccumulator
 from repro.models import Model
@@ -177,6 +195,9 @@ class ServingEngine:
         self.seqs: List[np.ndarray] = [np.zeros(0, np.int64)
                                        for _ in range(self.num_slots)]
         self.step_count = 0
+        # cache rows the decode steps read, and those live requests hold
+        self.kv_rows_read = 0
+        self.kv_rows_live = 0
         self._finished: List[Request] = []
         self._jit_prefill = jax.jit(self._prefill_impl)
         self._jit_decode = jax.jit(self._decode_impl, donate_argnums=(2,),
@@ -298,28 +319,41 @@ class ServingEngine:
             slot = free[0]
             req = self.scheduler.admit_next(slot, self.step_count)
             assert req is not None
-            kw = self._prefill_kwargs(req.prompt)
-            true_len = len(req.prompt)
-            s_tot = true_len + self._n_front
-            pc_kind, pc_entry = "miss", None
-            if self.prefix_cache is not None:
-                pc_kind, pc_entry = self.prefix_cache.lookup(req.prompt)
-                if pc_kind == "prefix" and self.telemetry is not None:
-                    # extension teacher-forces the suffix without capture,
-                    # so it cannot replay routing records — with telemetry
-                    # on, only exact hits skip the prefill
-                    pc_kind, pc_entry = "miss", None
-            caps_sliced: Dict[str, Any] = {}
-            if pc_kind == "exact":
-                # prefill is deterministic, so the stored prepared cache +
-                # last-token logits (and sliced captures, for telemetry
-                # replay) are bit-identical to re-prefilling this prompt
+            with TraceAnnotation("serving.admit", uid=req.uid,
+                                 prompt_len=len(req.prompt)) as span:
+                self._admit_one(req, slot, span)
+            admitted = True
+        return admitted
+
+    def _admit_one(self, req: Request, slot: int,
+                   span: TraceAnnotation) -> None:
+        """Prefill ``req`` (or take its cache from the prefix cache) into
+        ``slot`` and emit its first token."""
+        kw = self._prefill_kwargs(req.prompt)
+        true_len = len(req.prompt)
+        s_tot = true_len + self._n_front
+        pc_kind, pc_entry = "miss", None
+        if self.prefix_cache is not None:
+            pc_kind, pc_entry = self.prefix_cache.lookup(req.prompt)
+            if pc_kind == "prefix" and self.telemetry is not None:
+                # extension teacher-forces the suffix without capture,
+                # so it cannot replay routing records — with telemetry
+                # on, only exact hits skip the prefill
+                pc_kind, pc_entry = "miss", None
+        span.set_metadata(kind=pc_kind)
+        caps_sliced: Dict[str, Any] = {}
+        if pc_kind == "exact":
+            # prefill is deterministic, so the stored prepared cache +
+            # last-token logits (and sliced captures, for telemetry
+            # replay) are bit-identical to re-prefilling this prompt
+            with TraceAnnotation("serving.kv_insert"):
                 self.kv.insert(pc_entry.cache, slot, length=s_tot)
-                last_np = pc_entry.last_logits
-                caps_sliced = pc_entry.caps or {}
-            elif pc_kind == "prefix":
-                # extend the longest stored prefix by teacher-forcing the
-                # unseen suffix through the decode path, one token a step
+            last_np = pc_entry.last_logits
+            caps_sliced = pc_entry.caps or {}
+        elif pc_kind == "prefix":
+            # extend the longest stored prefix by teacher-forcing the
+            # unseen suffix through the decode path, one token a step
+            with TraceAnnotation("serving.prefill"):
                 cache = pc_entry.cache
                 logits = None
                 for t in range(len(pc_entry.prompt), true_len):
@@ -327,38 +361,47 @@ class ServingEngine:
                         self.params,
                         jnp.asarray(req.prompt[t][None, None]),
                         cache, jnp.int32(t))
+                with TraceAnnotation("serving.device_wait"):
+                    jax.block_until_ready(logits)
                 last_np = np.asarray(logits)[0]
-                self.prefix_cache.put(req.prompt, cache, last_np)
+            self.prefix_cache.put(req.prompt, cache, last_np)
+            with TraceAnnotation("serving.kv_insert"):
                 self.kv.insert(cache, slot, length=s_tot)
-            else:
-                bucket = self.prompt_bucket
-                padded = -(-true_len // bucket) * bucket
-                # prefilled cache (padded + frontend) must fit the slot
-                padded = min(padded, self.max_len - self._n_front)
-                toks = np.zeros(padded, np.int32)
-                toks[:true_len] = req.prompt
+        else:
+            bucket = self.prompt_bucket
+            padded = -(-true_len // bucket) * bucket
+            # prefilled cache (padded + frontend) must fit the slot
+            padded = min(padded, self.max_len - self._n_front)
+            toks = np.zeros(padded, np.int32)
+            toks[:true_len] = req.prompt
+            with TraceAnnotation("serving.prefill"):
                 last_logits, cache, caps = self._jit_prefill(
                     self.params, jnp.asarray(toks[None]),
                     kw["frontend"], kw["enc_tokens"],
                     jnp.int32(self._n_front + true_len - 1))
-                self.kv.insert(cache, slot, length=s_tot)
+                with TraceAnnotation("serving.kv_insert"):
+                    self.kv.insert(cache, slot, length=s_tot)
+                with TraceAnnotation("serving.device_wait"):
+                    jax.block_until_ready((last_logits, caps))
                 last_np = np.asarray(last_logits)[0]
-                if self.telemetry is not None:
+            if self.telemetry is not None:
+                with TraceAnnotation("serving.telemetry"):
                     caps_h = jax.tree.map(np.asarray, caps)
                     caps_sliced = self._sliced_prefill_captures(
                         caps_h, true_len)
-                if self.prefix_cache is not None:
-                    self.prefix_cache.put(
-                        req.prompt, cache, last_np,
-                        caps_sliced if self.telemetry is not None
-                        else None)
-            self.pos[slot] = s_tot
-            if self._enc_dec:
-                if self.cfg.frontend == "audio_stub":
-                    self.enc_valid[slot] = self.cfg.frontend_tokens
-                else:
-                    self.enc_valid[slot] = len(req.prompt)
-            if self.telemetry is not None:
+            if self.prefix_cache is not None:
+                self.prefix_cache.put(
+                    req.prompt, cache, last_np,
+                    caps_sliced if self.telemetry is not None
+                    else None)
+        self.pos[slot] = s_tot
+        if self._enc_dec:
+            if self.cfg.frontend == "audio_stub":
+                self.enc_valid[slot] = self.cfg.frontend_tokens
+            else:
+                self.enc_valid[slot] = len(req.prompt)
+        if self.telemetry is not None:
+            with TraceAnnotation("serving.telemetry"):
                 mark = self.telemetry.num_records
                 self.telemetry.record_prefill(req.prompt[None], caps_sliced)
                 if self.predictor is not None:
@@ -367,32 +410,35 @@ class ServingEngine:
                     self.predictor.observe_tokens(req.prompt)
                     self.predictor.update_records(
                         self.telemetry.records_since(mark))
-            first = int(last_np.argmax())
-            req.first_token_time = time.perf_counter()
-            if req.max_new_tokens < 1:
-                self.seqs[slot] = req.prompt.astype(np.int64)
+        first = int(last_np.argmax())
+        req.first_token_time = time.perf_counter()
+        if req.max_new_tokens < 1:
+            self.seqs[slot] = req.prompt.astype(np.int64)
+            self._finish(req, "length")
+            self.kv.release(slot)
+        else:
+            req.output.append(first)
+            self.seqs[slot] = np.append(req.prompt.astype(np.int64),
+                                        first)
+            self.cur_tok[slot] = first
+            eos = req.eos_id if req.eos_id is not None else self.eos_id
+            if eos is not None and first == eos:
+                self._finish(req, "eos")
+                self.kv.release(slot)
+            elif len(req.output) >= req.max_new_tokens:
                 self._finish(req, "length")
                 self.kv.release(slot)
-            else:
-                req.output.append(first)
-                self.seqs[slot] = np.append(req.prompt.astype(np.int64),
-                                            first)
-                self.cur_tok[slot] = first
-                eos = req.eos_id if req.eos_id is not None else self.eos_id
-                if eos is not None and first == eos:
-                    self._finish(req, "eos")
-                    self.kv.release(slot)
-                elif len(req.output) >= req.max_new_tokens:
-                    self._finish(req, "length")
-                    self.kv.release(slot)
-            admitted = True
-        return admitted
 
     # ------------------------------------------------------------------ step
     def step(self) -> bool:
         """Admit queued requests, then advance every live slot one token.
 
         Returns False when there was nothing to do."""
+        with StepTraceAnnotation("serving.step",
+                                 step_num=self.step_count) as span:
+            return self._step(span)
+
+    def _step(self, span: StepTraceAnnotation) -> bool:
         self._admit()
         active = [i for i, r in enumerate(self.scheduler.slots)
                   if r is not None]
@@ -404,70 +450,85 @@ class ServingEngine:
         # (the previous step's outputs), emitted before routing runs
         hints = None
         if self.predictor is not None:
-            act_tok = in_tok[np.asarray(active, np.int64)]
-            hints = self.predictor.prewarm_hint_matrix(act_tok)
-            self.last_prewarm_hints = hints
-        if self.cache is not None and hints is not None:
-            # residency hints: swap hinted experts in BEFORE the step's
-            # routing runs, so predicted-hot experts are already warm
-            self.cache.prefetch(hints)
-        cross_valid = (jnp.asarray(self.enc_valid) if self._enc_dec
-                       else None)
-        # ragged decode: a static attention bound covering the longest
-        # live slot AFTER this step's write (max valid rows + 1), rounded
-        # up to kv_len_bucket so recompiles stay bounded. Dead slots'
-        # rows are released, so the bound tracks live requests only.
-        kv_len = None
-        if self._ragged_decode:
-            need = self.kv.max_valid_len() + 1
-            b = self.kv_len_bucket
-            kv_len = min(-(-need // b) * b, self.max_len)
-        logits, cache, caps = self._jit_decode(
-            self.params, jnp.asarray(in_tok[:, None]), self.kv.cache,
-            jnp.asarray(in_pos), cross_valid, kv_len)
-        self.kv.update(cache)
+            with TraceAnnotation("serving.telemetry"):
+                act_tok = in_tok[np.asarray(active, np.int64)]
+                hints = self.predictor.prewarm_hint_matrix(act_tok)
+                self.last_prewarm_hints = hints
+                if self.cache is not None:
+                    # residency hints: swap hinted experts in BEFORE the
+                    # step's routing runs, so predicted-hot experts are
+                    # already warm
+                    self.cache.prefetch(hints)
+        with TraceAnnotation("serving.decode"):
+            cross_valid = (jnp.asarray(self.enc_valid) if self._enc_dec
+                           else None)
+            # ragged decode: a static attention bound covering the longest
+            # live slot AFTER this step's write (max valid rows + 1),
+            # rounded up to kv_len_bucket so recompiles stay bounded. Dead
+            # slots' rows are released, so the bound tracks live requests
+            # only.
+            kv_len = None
+            if self._ragged_decode:
+                need = self.kv.max_valid_len() + 1
+                b = self.kv_len_bucket
+                kv_len = min(-(-need // b) * b, self.max_len)
+            # every slot reads its rows up to the bound; after the write a
+            # live slot holds pos + 1 of them
+            rows = self.max_len if kv_len is None else kv_len
+            self.kv_rows_read += self.num_slots * rows
+            self.kv_rows_live += int(in_pos[active].sum()) + len(active)
+            span.set_metadata(live=len(active), kv_len=rows)
+            logits, cache, caps = self._jit_decode(
+                self.params, jnp.asarray(in_tok[:, None]), self.kv.cache,
+                jnp.asarray(in_pos), cross_valid, kv_len)
+            self.kv.update(cache)
+        with TraceAnnotation("serving.device_wait"):
+            jax.block_until_ready((logits, caps))
         if self.telemetry is not None:
-            caps_h = jax.tree.map(np.asarray, caps)
-            demand_before = (self.telemetry.demand.copy()
-                             if hints is not None or self.cache is not None
-                             else None)
-            mark = self.telemetry.num_records
-            self.telemetry.record_decode(
-                in_tok, in_pos - self._n_front, self.seqs, caps_h, active,
-                n_front=self._n_front)
-            if self.cache is not None:
-                # score the step's ACTUAL routing against residency
-                self.cache.serve_demand(
-                    self.telemetry.demand - demand_before)
-            if hints is not None:
-                # score the hints against what the step actually routed,
-                # THEN learn from the step (hints stay strictly causal)
-                self.telemetry.record_prewarm(
-                    hints, self.telemetry.demand - demand_before)
-                self.predictor.observe_tokens(
-                    in_tok[np.asarray(active, np.int64)])
-                self.predictor.update_records(
-                    self.telemetry.records_since(mark))
-        nxt = np.asarray(logits).argmax(-1)
-        for i in active:
-            req = self.scheduler.slots[i]
-            assert req is not None
-            tok = int(nxt[i])
-            req.output.append(tok)
-            self.seqs[i] = np.append(self.seqs[i], tok)
-            self.pos[i] += 1
-            self.cur_tok[i] = tok
-            self.kv.set_length(i, int(self.pos[i]))
-            eos = req.eos_id if req.eos_id is not None else self.eos_id
-            if eos is not None and tok == eos:
-                self._finish(req, "eos")
-                self.kv.release(i)
-            elif len(req.output) >= req.max_new_tokens:
-                self._finish(req, "length")
-                self.kv.release(i)
-            elif self.pos[i] >= self.max_len:
-                self._finish(req, "truncated")   # KV capacity exhausted
-                self.kv.release(i)
+            with TraceAnnotation("serving.telemetry"):
+                caps_h = jax.tree.map(np.asarray, caps)
+                demand_before = (self.telemetry.demand.copy()
+                                 if hints is not None or self.cache is not None
+                                 else None)
+                mark = self.telemetry.num_records
+                self.telemetry.record_decode(
+                    in_tok, in_pos - self._n_front, self.seqs, caps_h,
+                    active, n_front=self._n_front)
+                if self.cache is not None:
+                    # score the step's ACTUAL routing against residency
+                    self.cache.serve_demand(
+                        self.telemetry.demand - demand_before)
+                if hints is not None:
+                    # score the hints against what the step actually
+                    # routed, THEN learn from the step (hints stay
+                    # strictly causal)
+                    self.telemetry.record_prewarm(
+                        hints, self.telemetry.demand - demand_before)
+                    self.predictor.observe_tokens(
+                        in_tok[np.asarray(active, np.int64)])
+                    self.predictor.update_records(
+                        self.telemetry.records_since(mark))
+        with TraceAnnotation("serving.sample"):
+            nxt = np.asarray(logits).argmax(-1)
+            for i in active:
+                req = self.scheduler.slots[i]
+                assert req is not None
+                tok = int(nxt[i])
+                req.output.append(tok)
+                self.seqs[i] = np.append(self.seqs[i], tok)
+                self.pos[i] += 1
+                self.cur_tok[i] = tok
+                self.kv.set_length(i, int(self.pos[i]))
+                eos = req.eos_id if req.eos_id is not None else self.eos_id
+                if eos is not None and tok == eos:
+                    self._finish(req, "eos")
+                    self.kv.release(i)
+                elif len(req.output) >= req.max_new_tokens:
+                    self._finish(req, "length")
+                    self.kv.release(i)
+                elif self.pos[i] >= self.max_len:
+                    self._finish(req, "truncated")   # KV capacity exhausted
+                    self.kv.release(i)
         self.step_count += 1
         return True
 
