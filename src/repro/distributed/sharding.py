@@ -151,10 +151,11 @@ def cache_shardings(cfg: ModelConfig, cache_shape: Any, mesh: Mesh,
                     batch_size: int) -> Any:
     """Shardings for the decode cache.
 
-    Attention K/V (nb, B, T, nkv, hd): batch over data when divisible; for
+    Attention K/V (nb, B, T, nkv*hd): batch over data when divisible; for
     global-attention caches with batch=1 (long_500k) the TIME axis shards
-    over 'data' instead (sequence parallelism over the cache); KV heads over
-    'model' when divisible. Recurrent states shard batch over data and the
+    over 'data' instead (sequence parallelism over the cache); the fused
+    head axis over 'model' when the KV heads divide (each shard then holds
+    whole heads). Recurrent states shard batch over data and the
     head/d_inner dim over 'model' when divisible.
     """
     ms = _model_size(mesh)
@@ -169,16 +170,15 @@ def cache_shardings(cfg: ModelConfig, cache_shape: Any, mesh: Mesh,
         names = tuple(p.key if hasattr(p, "key") else str(p) for p in path)
         shape = leaf.shape
         if "attn" in names or "cross" in names:
-            # (nb, B, T, nkv, hd)
-            nkv_ok = _div(shape[3], ms)
+            # (nb, B, T, nkv*hd)
+            heads = "model" if _div(cfg.num_kv_heads, ms) else None
             if bspec is not None:
-                spec = P(None, bspec, None, "model" if nkv_ok else None, None)
+                spec = P(None, bspec, None, heads)
             elif _div(shape[2], dsize) and shape[2] >= dsize:
                 seq_ax = data_axes if len(data_axes) > 1 else data_axes[0]
-                spec = P(None, None, seq_ax,
-                         "model" if nkv_ok else None, None)
+                spec = P(None, None, seq_ax, heads)
             else:
-                spec = P(None, None, None, "model" if nkv_ok else None, None)
+                spec = P(None, None, None, heads)
         elif names[-1] == "state" and len(shape) == 5:  # mamba (nb,B,H,P,N)
             h_ok = _div(shape[2], ms)
             spec = P(None, bspec, "model" if h_ok else None, None, None)

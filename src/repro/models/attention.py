@@ -8,8 +8,12 @@ Two compute paths:
             reshape) so long-context shapes have a bounded working set. This
             is the pure-jnp twin of ``repro.kernels.decode_attention``.
 
-Shapes: x (B, S, d); caches (B, T, n_kv, hd). GQA is computed grouped
-(q reshaped to (B, S, n_kv, group, hd)) -- no KV head repetition.
+Shapes: x (B, S, d); K/V caches (B, T, n_kv·hd), heads and head dim
+fused into the minor axis so no (n_kv, hd) tile pads them; decode reads
+and writes them stacked over the model's blocks, (num_blocks, B, T,
+n_kv·hd), and views the rows it scores as (B, T, n_kv, hd). GQA is
+computed grouped (q reshaped to (B, S, n_kv, group, hd)) -- no KV head
+repetition.
 """
 from __future__ import annotations
 
@@ -180,8 +184,9 @@ def attention_forward(
 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray], Optional[jnp.ndarray]]:
     """Full-sequence attention. Returns (y, cache_kv, attn_argmax).
 
-    ``cache_kv`` holds the rope'd K/V to seed decoding: for windowed layers it
-    is the rolling last-``window`` slice, otherwise the full sequence.
+    ``cache_kv`` holds the rope'd K/V to seed decoding, (B, T, n_kv·hd):
+    for windowed layers it is the rolling last-``window`` slice, otherwise
+    the full sequence.
     """
     nh = num_heads or cfg.num_heads
     nkv = num_kv_heads or cfg.num_kv_heads
@@ -219,15 +224,12 @@ def attention_forward(
     y = jnp.moveaxis(out, 3, 1).reshape(B, S, nh * hd).astype(x.dtype)
     y = y @ params["wo"]
 
-    if cross:
-        cache = {"k": k, "v": v}
-    elif window > 0:
+    k, v = k.reshape(B, T, nkv * hd), v.reshape(B, T, nkv * hd)
+    if window > 0 and not cross:
         W = min(window, T)
-        tail_k = k[:, T - W:]
-        tail_v = v[:, T - W:]
         shift = (T - W) % W if W else 0
-        cache = {"k": jnp.roll(tail_k, shift, axis=1),
-                 "v": jnp.roll(tail_v, shift, axis=1)}
+        cache = {"k": jnp.roll(k[:, T - W:], shift, axis=1),
+                 "v": jnp.roll(v[:, T - W:], shift, axis=1)}
     else:
         cache = {"k": k, "v": v}
     return y, cache, attn_argmax
@@ -237,9 +239,10 @@ def attention_decode_step(
     params: Params,
     cfg: ModelConfig,
     x: jnp.ndarray,                 # (B, 1, d)
-    cache: Dict[str, jnp.ndarray],  # k/v: (B, T, n_kv, hd)
+    cache: Dict[str, jnp.ndarray],  # k/v: see below
     *,
     pos,                            # absolute position: scalar or (B,) vector
+    layer,                          # self-attention: block index in the stack
     causal: bool = True,
     window: int = 0,
     rope_theta: float = 0.0,
@@ -254,6 +257,15 @@ def attention_decode_step(
 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray], Optional[jnp.ndarray]]:
     """One-token decode against a KV cache. Returns (y, new_cache, argmax).
 
+    Self-attention takes the k/v caches of every block, stacked
+    (num_blocks, B, T, n_kv·hd), and the traced index ``layer`` of this
+    block: it writes each row's new K/V at ``[layer, row, slot]`` in place
+    (the decode scan carries the stacks) and reads back only this layer's
+    rows it scores, so no layer's cache is copied out at ``T`` and written
+    back. Cross-attention (``cross``) reads this layer's static encoder
+    K/V, (B, S, n_kv·hd), ``valid_len`` masking encoder padding, and writes
+    nothing. The rows read are viewed as (B, T', n_kv, hd).
+
     ``pos`` may be a scalar (whole batch at one position — the training /
     consistency-test path) or a (B,) vector of per-row positions (the
     continuous-batching serving path, where every slot decodes at its own
@@ -266,11 +278,11 @@ def attention_decode_step(
 
     ``kv_len`` is a STATIC ragged-decode hint from the serving engine:
     every row's validity (``pos + 1``) is promised to be <= ``kv_len``
-    this step, so the attention read slices the cache to its first
-    ``kv_len`` slots instead of scoring all ``max_len`` padded positions
-    (the cache write above still targets the full buffer). Ignored for
-    windowed layers (their rolling cache wraps, so high slot indices stay
-    live) and cross-attention.
+    this step, so the attention read takes the layer's first ``kv_len``
+    slots instead of scoring all ``max_len`` padded positions (the write
+    still targets the full buffer). Ignored for windowed layers (their
+    rolling cache wraps, so high slot indices stay live) and
+    cross-attention.
 
     ``backend`` selects the attention realization: ``"jnp"`` (dense
     einsum under ``dense_threshold``, blocked flash above) or
@@ -281,9 +293,7 @@ def attention_decode_step(
     The cache update runs under the name scope ``kv_write``.
 
     Windowed layers use a rolling cache of ``window`` slots (write at
-    ``pos % window``); full layers write at ``pos``. Cross-attention reads a
-    static cache (encoder K/V, ``valid_len`` masks encoder padding) and
-    writes nothing.
+    ``pos % window``); full layers write at ``pos``.
 
     ``capture`` (dense path only) returns the per-row argmax key position
     summed over heads — the paper's attention-ID feature — else None.
@@ -292,7 +302,7 @@ def attention_decode_step(
     nkv = num_kv_heads or cfg.num_kv_heads
     hd = cfg.resolved_head_dim
     B = x.shape[0]
-    T = cache["k"].shape[1]
+    T = cache["k"].shape[-2]
     g = nh // nkv
     pos = jnp.asarray(pos)
     per_slot = pos.ndim == 1
@@ -306,7 +316,7 @@ def attention_decode_step(
         q = apply_rope(q, rope_pos, inv)
 
     if cross:
-        k, v = cache["k"], cache["v"]
+        k_att, v_att, T_att = cache["k"], cache["v"], T
         valid = T if valid_len is None else valid_len
         new_cache = cache
     else:
@@ -316,24 +326,31 @@ def attention_decode_step(
             knew = apply_norm("rmsnorm", params["k_norm"], knew)
         if rope_theta > 0:
             knew = apply_rope(knew, rope_pos, inv)
+        dt = cache["k"].dtype
+        knew = knew.reshape(B, nkv * hd).astype(dt)
+        vnew = vnew.reshape(B, nkv * hd).astype(dt)
         slot = pos % T if window > 0 else pos
         with jax.named_scope("kv_write"):
             if per_slot:
                 rows = jnp.arange(B)
-                k = cache["k"].at[rows, slot].set(knew[:, 0], mode="drop")
-                v = cache["v"].at[rows, slot].set(vnew[:, 0], mode="drop")
+                k = cache["k"].at[layer, rows, slot].set(knew, mode="drop")
+                v = cache["v"].at[layer, rows, slot].set(vnew, mode="drop")
             else:
-                k = jax.lax.dynamic_update_slice(cache["k"], knew,
-                                                 (0, slot, 0, 0))
-                v = jax.lax.dynamic_update_slice(cache["v"], vnew,
-                                                 (0, slot, 0, 0))
+                at = (layer, 0, slot, 0)
+                k = jax.lax.dynamic_update_slice(cache["k"],
+                                                 knew[None, :, None], at)
+                v = jax.lax.dynamic_update_slice(cache["v"],
+                                                 vnew[None, :, None], at)
         valid = jnp.minimum(pos + 1, T) if window > 0 else pos + 1
         new_cache = {"k": k, "v": v}
-
-    # ragged-decode hint: score only the slots that can be valid
-    k_att, v_att, T_att = k, v, T
-    if (kv_len is not None and not cross and window == 0 and kv_len < T):
-        k_att, v_att, T_att = k[:, :kv_len], v[:, :kv_len], kv_len
+        # ragged-decode hint: read only the slots that can be valid
+        T_att = kv_len if (kv_len is not None and window == 0
+                           and kv_len < T) else T
+        rows_read = (1, B, T_att, nkv * hd)
+        k_att = jax.lax.dynamic_slice(k, (layer, 0, 0, 0), rows_read)[0]
+        v_att = jax.lax.dynamic_slice(v, (layer, 0, 0, 0), rows_read)[0]
+    k_att = k_att.reshape(B, T_att, nkv, hd)
+    v_att = v_att.reshape(B, T_att, nkv, hd)
 
     qg = jnp.moveaxis(q.reshape(B, 1, nkv, g, hd), 1, 3)
     kt = jnp.moveaxis(k_att, 1, 2)
@@ -370,5 +387,5 @@ def init_cache(cfg: ModelConfig, batch: int, seq_len: int, *,
     nkv = num_kv_heads or cfg.num_kv_heads
     hd = cfg.resolved_head_dim
     T = min(window, seq_len) if window > 0 else seq_len
-    shape = (batch, T, nkv, hd)
+    shape = (batch, T, nkv * hd)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}

@@ -89,11 +89,9 @@ def init_block_cache(cfg: ModelConfig, spec: LayerSpec, batch: int,
     elif spec.mixer == "slstm":
         cache["ssm"] = ssm_mod.init_slstm_cache(cfg, batch, dtype)
     if cross_len > 0:
-        hd = cfg.resolved_head_dim
-        cache["cross"] = {
-            "k": jnp.zeros((batch, cross_len, cfg.num_kv_heads, hd), dtype),
-            "v": jnp.zeros((batch, cross_len, cfg.num_kv_heads, hd), dtype),
-        }
+        shape = (batch, cross_len, cfg.num_kv_heads * cfg.resolved_head_dim)
+        cache["cross"] = {"k": jnp.zeros(shape, dtype),
+                          "v": jnp.zeros(shape, dtype)}
     return cache
 
 
@@ -201,6 +199,7 @@ def block_decode_step(
     cache: Dict[str, Any],
     *,
     pos,
+    layer,
     capture: bool = False,
     cross_valid=None,
     moe_ffn_fn=None,
@@ -213,6 +212,12 @@ def block_decode_step(
     attn_backend: str = "jnp",
 ) -> Tuple[jnp.ndarray, Dict[str, Any], Dict[str, Any]]:
     """Returns (x, new_cache, captured). ``pos`` may be scalar or (B,).
+
+    ``cache`` holds this block's own leaves (``ssm`` states, the ``cross``
+    encoder K/V) and, for an attention mixer, under ``attn`` the k/v
+    stacks of every block at its unit position, (num_blocks, B, T,
+    n_kv·hd): :func:`attention_decode_step` writes them in place at
+    ``layer`` and ``new_cache["attn"]`` returns the updated stacks.
 
     Under ``capture``, ``captured`` mirrors :func:`block_forward`'s capture
     dict for the single decoded token: ``attn_argmax`` (B, 1) and the MoE
@@ -232,8 +237,9 @@ def block_decode_step(
         rope = cfg.rope_theta if cfg.pos_embed == "rope" else 0.0
         with jax.named_scope("attention"):
             y, kv, argmax = attention_decode_step(
-                attn_p, cfg, h, cache["attn"], pos=pos, causal=cfg.causal,
-                window=window, rope_theta=rope, capture=capture,
+                attn_p, cfg, h, cache["attn"], pos=pos, layer=layer,
+                causal=cfg.causal, window=window, rope_theta=rope,
+                capture=capture,
                 dense_threshold=dense_threshold, kv_len=kv_len,
                 backend=attn_backend)
         new_cache["attn"] = kv
@@ -258,8 +264,8 @@ def block_decode_step(
     if "cross" in cache:
         h = apply_norm(cfg.norm, params["norm_cross"], x)
         y, _, _ = attention_decode_step(params["cross"], cfg, h,
-                                        cache["cross"], pos=pos, cross=True,
-                                        valid_len=cross_valid)
+                                        cache["cross"], pos=pos, layer=layer,
+                                        cross=True, valid_len=cross_valid)
         x = x + y
         new_cache["cross"] = cache["cross"]
 

@@ -248,7 +248,13 @@ class Model:
     # --------------------------------------------------------------- serving
     def init_cache(self, batch: int, seq_len: int, *,
                    dtype=jnp.float32) -> Dict[str, Any]:
-        """Zero decode cache, stacked (num_blocks, ...) per unit position."""
+        """Zero decode cache, stacked (num_blocks, ...) per unit position.
+
+        Self-attention K/V (full, windowed ``swa`` and ``shared_attn``
+        mixers) are (num_blocks, B, T, n_kv·hd): heads and head dim fused
+        into the minor axis, the layout :meth:`decode_step` carries
+        through its layer scan and writes in place; cross-attention K/V
+        share it, recurrent states keep their own shapes."""
         cfg = self.cfg
         cross_len = cfg.encoder.source_len if cfg.is_encoder_decoder else 0
 
@@ -266,10 +272,12 @@ class Model:
                              max_len: int) -> Dict[str, Any]:
         """Pad prefill caches to decode-buffer sizes.
 
-        Full-attention K/V grow from the prefilled length to ``max_len``
-        (zeros beyond the valid prefix are masked by position validity);
-        rolling-window caches pad up to ``window`` slots; recurrent states
-        and cross caches pass through unchanged.
+        Full-attention K/V, (num_blocks, B, T, n_kv·hd) as
+        :meth:`init_cache` lays them out, grow along ``T`` from the
+        prefilled length to ``max_len`` (zeros beyond the valid prefix are
+        masked by position validity); rolling-window caches pad up to
+        ``window`` slots; recurrent states and cross caches pass through
+        unchanged.
         """
         cfg = self.cfg
         out: Dict[str, Any] = {}
@@ -280,7 +288,7 @@ class Model:
                 target = min(window, max_len) if window > 0 else max_len
                 kv = {}
                 for kname, arr in cp["attn"].items():
-                    T = arr.shape[2]   # (num_blocks, B, T, nkv, hd)
+                    T = arr.shape[2]   # (num_blocks, B, T, nkv*hd)
                     if T < target:
                         pad = [(0, 0)] * arr.ndim
                         pad[2] = (0, target - T)
@@ -324,7 +332,13 @@ class Model:
         that every row's ``pos + 1 <= kv_len`` this step, letting
         full-attention layers score a sliced cache instead of the whole
         ``max_len`` buffer (callers re-jit per distinct value — bucket
-        it). ``attn_backend``: "jnp" | "pallas" decode attention."""
+        it). ``attn_backend``: "jnp" | "pallas" decode attention.
+
+        The layer scan carries the self-attention K/V stacks,
+        (num_blocks, B, T, n_kv·hd), with the scanned block index, and each
+        block writes its new row into them in place: no layer's cache is
+        sliced out at ``T`` and stacked back. Recurrent states and
+        read-only cross caches are scanned per block."""
         cfg = self.cfg
         executor = moe_executor or self.moe_executor
         router_impl = moe_router_impl or self.moe_router_impl
@@ -340,15 +354,23 @@ class Model:
                     x = x + jax.lax.dynamic_slice_in_dim(
                         params["pos_table"], pos, 1, axis=0)
         shared = params["shared"]
+        carried = {f"pos{p}" for p, spec in enumerate(cfg.pattern)
+                   if spec.mixer in B.ATTN_MIXERS}
+        kv = {n: cache[n]["attn"] for n in carried}
+        scanned = {n: {k: a for k, a in c.items() if k != "attn"}
+                   for n, c in cache.items()}
 
-        def body(h, xs):
-            blk_params, blk_cache = xs
-            new_caches, caps = {}, {}
+        def body(carry, xs):
+            h, kv = carry
+            layer, blk_params, blk_cache = xs
+            kv, new_caches, caps = dict(kv), {}, {}
             for p, spec in enumerate(cfg.pattern):
+                n = f"pos{p}"
+                c = {**blk_cache[n], "attn": kv[n]} if n in kv \
+                    else blk_cache[n]
                 h, nc, cap = B.block_decode_step(
-                    blk_params[f"pos{p}"], shared, cfg, spec, h,
-                    blk_cache[f"pos{p}"], pos=pos, capture=capture,
-                    cross_valid=cross_valid,
+                    blk_params[n], shared, cfg, spec, h, c, pos=pos,
+                    layer=layer, capture=capture, cross_valid=cross_valid,
                     moe_ffn_fn=self.moe_ffn_fn,
                     moe_layer_fn=self.moe_layer_fn,
                     moe_executor=executor,
@@ -356,12 +378,17 @@ class Model:
                     moe_router_impl=router_impl,
                     dense_threshold=self.decode_dense_threshold,
                     kv_len=kv_len, attn_backend=backend)
-                new_caches[f"pos{p}"] = nc
-                caps[f"pos{p}"] = cap
-            return h, (new_caches, caps)
+                if n in kv:
+                    kv[n] = nc.pop("attn")
+                new_caches[n] = nc
+                caps[n] = cap
+            return (h, kv), (new_caches, caps)
 
-        x, (new_cache, caps) = jax.lax.scan(body, x,
-                                            (params["blocks"], cache))
+        (x, kv), (new_cache, caps) = jax.lax.scan(
+            body, (x, kv),
+            (jnp.arange(cfg.num_blocks), params["blocks"], scanned))
+        for n in carried:
+            new_cache[n]["attn"] = kv[n]
         with jax.named_scope("head"):
             x = apply_norm(cfg.norm, params["final_norm"], x)
             logits = x @ (params["embed"].T if cfg.tie_embeddings

@@ -1,7 +1,8 @@
 """Per-slot KV-cache management for continuous batching.
 
 The engine keeps ONE slot-batched decode cache (leaves stacked
-``(num_blocks, num_slots, ...)``) alive for its whole life; admitting a
+``(num_blocks, num_slots, ...)``; self-attention K/V as ``(num_blocks,
+num_slots, max_len, n_kv·hd)``) alive for its whole life; admitting a
 request prefills it alone (batch 1, exact prompt length — no padding, so
 ragged prompts never leak pad keys into attention) and scatters the
 prepared single-request cache into the free slot's row. Releasing a slot
@@ -25,6 +26,11 @@ from repro.models import Model
 
 class SlotKVCache:
     """Slot-batched decode cache with jitted single-slot insertion.
+
+    Self-attention K/V leaves are ``(num_blocks, num_slots, max_len,
+    n_kv·hd)``: the layout the engine's decode step carries through its
+    layer scan and writes in place, one row per slot, so a step neither
+    copies a layer's cache out nor the stack back (the step donates it).
 
     Tracks per-slot VALID lengths host-side (``lengths[slot]`` = number of
     cache rows holding real tokens). The ragged-decode path reads

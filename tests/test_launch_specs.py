@@ -91,6 +91,32 @@ def test_collective_parser():
     assert counts["collective-permute"] == 0
 
 
+@pytest.mark.parametrize("name,shape,want", [
+    ("gpt2-moe", "decode_32k", ("data", None, "model")),
+    ("granite-34b", "decode_32k", ("data", None, None)),    # one KV head
+    ("gemma3-12b", "long_500k", (None, "data", "model")),   # batch 1
+    ("whisper-small", "decode_32k", ("data", None, "model")),
+])
+def test_decode_cache_specs_fuse_kv_heads(name, shape, want):
+    """Decode input specs lay every attention cache out (nb, B, T,
+    nkv*hd) and shard batch or time over data and the fused head axis
+    over model only when the KV heads divide it."""
+    from jax.sharding import AbstractMesh
+    from repro.launch.specs import input_specs
+    cfg, _ = tiny_model(name)
+    specs = input_specs(cfg, SHAPES[shape],
+                        AbstractMesh((2, 2), ("data", "model")))
+    flat = jax.tree_util.tree_flatten_with_path(specs["cache"])[0]
+    kv = [(jax.tree_util.keystr(p), s) for p, s in flat
+          if "'attn'" in jax.tree_util.keystr(p)
+          or "'cross'" in jax.tree_util.keystr(p)]
+    assert kv
+    hd = cfg.resolved_head_dim
+    for path, s in kv:
+        assert s.ndim == 4 and s.shape[-1] == cfg.num_kv_heads * hd, path
+        assert tuple(s.sharding.spec) == (None,) + want, path
+
+
 def test_dense_threshold_switches_decode_path():
     """dense_threshold above the cache length must not change results."""
     cfg, model = tiny_model("codeqwen1.5-7b")

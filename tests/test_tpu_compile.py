@@ -1,4 +1,5 @@
-"""The serving path's Pallas kernels compile for a TPU v5e.
+"""The serving path's Pallas kernels, and its decode step, compile for a
+TPU v5e.
 
 Each case lowers a kernel wrapper with ``interpret=False`` against a
 described (not attached) ``v5e:2x2`` topology, at gpt2-moe's published
@@ -8,21 +9,33 @@ to low hundreds of tokens). Mosaic refuses here what interpret mode
 accepts: unaligned blocks, unsupported primitives, relayouts. Nothing
 runs, so these say nothing about results or speed.
 
+The decode-step cases compile the serving engine's whole decode program
+at a benchmark cell's shapes and read XLA's buffers: the layer scan must
+update the stacked KV cache in place, so no op copies the stack or holds
+one layer's cache at ``max_len``.
+
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and under several test workers only
 the worker given this file may.
 """
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+import repro.configs  # noqa: F401  (registers the architectures)
+from repro.config import get_arch
 from repro.kernels.decode_attention.ops import decode_attention_pallas
 from repro.kernels.expert_ffn.ops import expert_ffn_pallas
 from repro.kernels.grouped_moe.ops import grouped_moe_pallas
 from repro.kernels.router_topk.ops import (router_topk_fused_pallas,
                                            router_topk_pallas)
+from repro.models import Model
 from repro.models.moe import grouped_rows_for
+from repro.serving import ServingEngine
 
 D, FF, E, K = 768, 3072, 4, 1          # gpt2-moe
 SLOTS, MAX_LEN, HEADS, HEAD_DIM = 8, 1024, 12, 64
@@ -115,3 +128,73 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
     compiled = jax.jit(fn).lower(*args).compile()
     # the kernel was lowered by Mosaic, not inlined as interpreted jnp
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# (architecture, program options, decode slots): the benchmark cells'
+# engines, 1024 cache rows, a step whose longest live request reads 1008
+DECODE_CELLS = {
+    "gpt2-moe": ("gpt2-moe", {"tie_embeddings": True}, 64),
+    "granite-moe-3b-a800m": ("granite-moe-3b-a800m", {}, 24),
+}
+DECODE_KV_LEN = 1008
+IN_PLACE = ("scatter", "dynamic-update-slice")
+OP = re.compile(r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(")
+
+
+def _hlo_ops(text):
+    """(name, element count, opcode, fused root opcode or None) of every
+    array-valued op, and no parameter, in a compiled module's text."""
+    roots, ops, comp = {}, [], None
+    for line in text.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            comp = line.split()[1 if line.startswith("ENTRY") else 0]
+            comp = comp.lstrip("%")
+        m = OP.match(line)
+        if not m:
+            continue
+        name, dims, opcode = m.groups()
+        if line.lstrip().startswith("ROOT"):
+            roots[comp] = opcode
+        if opcode == "parameter":
+            continue
+        n = 1
+        for d in filter(None, dims.split(",")):
+            n *= int(d)
+        called = re.search(r"calls=%([\w.\-]+)", line)
+        ops.append((name, n, opcode, called and called.group(1)))
+    return [(name, n, opcode, roots.get(c) if c else None)
+            for name, n, opcode, c in ops]
+
+
+@pytest.mark.parametrize("cell", sorted(DECODE_CELLS))
+def test_decode_step_updates_the_cache_in_place(cell, one_chip,
+                                                no_persistent_cache):
+    arch, program, slots = DECODE_CELLS[cell]
+    cfg = dataclasses.replace(get_arch(arch), **program)
+    model = Model(cfg)
+    eng = ServingEngine(model, None, max_len=16, batch_size=1)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda k: model.init_params(k, dtype=BF16), jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(lambda: model.init_cache(slots,
+                                                            MAX_LEN)))
+    stack = {leaf.size for leaf in jax.tree.leaves(cache)}
+    layer = {n // cfg.num_blocks for n in stack}
+    compiled = eng._jit_decode.lower(
+        params, on_chip(jax.ShapeDtypeStruct((slots, 1), I32)), cache,
+        on_chip(jax.ShapeDtypeStruct((slots,), I32)), None,
+        DECODE_KV_LEN).compile()
+    ops = _hlo_ops(compiled.as_text())
+    assert ops
+    # no layer's cache sliced out, or written back, at max_len
+    assert not [op for op in ops if op[1] in layer]
+    # the stack itself only passes through the loop and is updated in place
+    moved = [op for op in ops if op[1] in stack
+             and op[2] not in ("get-tuple-element", "bitcast") + IN_PLACE
+             and not (op[2] == "fusion" and op[3] in IN_PLACE)]
+    assert not moved
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
