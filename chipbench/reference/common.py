@@ -25,12 +25,22 @@ smallest, in units of the base row's standard deviation.
 ``mode="fp8"`` is the control: the same model with both operands of
 every matrix product rounded to float8 e4m3 under a per-tensor (weights)
 or per-row (activations) scale, the precision below the model's bfloat16.
+
+What every configuration shares lives here: the embedding, final norm
+and head, the float32 and float8 arithmetic, the near-tie search and the
+per-layer path bookkeeping. The layers themselves are a list of
+:class:`Layer`, in the model's order. A configuration's reference module
+(``reference/<name>.py``) may give its own with ``layers(cfg, params)``;
+without it, :func:`default_layers` applies: grouped-query attention with
+rotate-half rotary positions (or learned positions), then the routed MoE
+layer (softmax router, top-k renormalized) or a dense feed-forward, as the
+layer's parameters hold, over the program's scanned pattern.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
 from functools import partial
-from typing import List, Tuple
+from typing import Any, Callable, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -60,6 +70,40 @@ class Arch:
     rope_theta: float
     act: str             # "gelu" (tanh form) | "swiglu"
     tied: bool
+
+
+@dataclass(frozen=True)
+class Layer:
+    """One layer of the model, as the reference computes it.
+
+    ``weights()`` returns the layer's parameters, taken from the model's
+    when called (so that one layer's copy is alive at a time); each
+    function takes them as its first argument:
+
+    * ``full(lp, x, mode)``: the block over a whole sequence x (S, d),
+      causal, in ``mode`` ("f32" or the "fp8" control). Returns the block
+      output (S, d), the state the single-token paths read from this
+      pass (opaque here: keys and values, or a latent cache), the
+      post-attention residual (S, d), and the router's selection scores
+      (S, E), or ``None`` for a layer without a router;
+    * ``attend(lp, h, t, state)``: the attention half for P single-token
+      paths, each with its block input h (P, d) at its position t (P,),
+      against the base pass's ``state``. Returns the post-attention
+      residual (P, d) and the scores (P, E) or ``None``;
+    * ``ffn(lp, u, scores, chosen)``: the rest of the block for P paths
+      from their residual u under the expert choice ``chosen`` (P, E)
+      bool (``None`` without a router). How the choice is weighted is
+      the layer's own.
+
+    ``top_k``: the experts a token chooses, the top ``top_k`` of its
+    scores, whose boundary the near-tie search reads (0 without a router).
+    """
+
+    weights: Callable[[], Any]
+    full: Callable
+    attend: Callable
+    ffn: Callable
+    top_k: int = 0
 
 
 # --------------------------------------------------------------- arithmetic
@@ -105,11 +149,8 @@ def gelu_tanh(x):
 def expert_out(a: Arch, p, h, mode):
     """Every expert on every row: h (N, d) -> (E, N, d)."""
     def one(e):
-        if a.act == "swiglu":
-            g = mm(h, p["w_gate"][e], mode)
-            u = mm(h, p["w_up"][e], mode)
-            return mm(g * jax.nn.sigmoid(g) * u, p["w_down"][e], mode)
-        return mm(gelu_tanh(mm(h, p["w_in"][e], mode)), p["w_out"][e], mode)
+        return dense_ffn(a, {k: w[e] for k, w in p.items()
+                             if k.startswith("w_")}, h, mode)
     return jax.lax.map(one, jnp.arange(a.num_experts))
 
 
@@ -141,12 +182,11 @@ def embed(a: Arch, params, tokens, *, mode):
     return x
 
 
-@partial(jax.jit, static_argnames=("a", "mode"))
-def base_layer(a: Arch, lp, x, *, mode):
-    """One block over a whole sequence x (S, d), causal. Returns the block
-    output, this layer's keys and values (S, nkv, hd), the post-attention
-    residual (S, d) and the router logits (S, E)."""
-    lp = f32(lp)
+def attend_sequence(a: Arch, lp, x, mode):
+    """Grouped-query attention half of a block over a whole sequence x
+    (S, d), causal, on float32 parameters ``lp``. Returns the
+    post-attention residual (S, d) and this layer's keys and values
+    (S, nkv, hd)."""
     S = x.shape[0]
     nh, nkv, hd = a.num_heads, a.num_kv_heads, a.head_dim
     h = norm(a, lp["norm1"], x)
@@ -165,24 +205,16 @@ def base_layer(a: Arch, lp, x, *, mode):
     s = jnp.where(causal, s, NEG)
     o = jnp.einsum("ngst,tnd->sngd", jax.nn.softmax(s, -1), v,
                    precision=jax.lax.Precision.HIGHEST).reshape(S, nh * hd)
-    u = x + mm(o, at["wo"], mode)
-    h2 = norm(a, lp["norm2"], u)
-    logits = mm(h2, lp["moe"]["router"], mode)
-    w = gate_weights(a, logits, own_choice(a, logits))
-    y = jnp.einsum("ne,end->nd", w, expert_out(a, lp["moe"], h2, mode),
-                   precision=jax.lax.Precision.HIGHEST)
-    return u + y, k, v, u, logits
+    return x + mm(o, at["wo"], mode), (k, v)
 
 
-@partial(jax.jit, static_argnames=("a",))
-def path_attention(a: Arch, lp, h, t, K, V):
-    """Attention half of one block for P single-token paths.
-
-    h (P, d): each path's block input at its position t (P,); K, V
-    (S, nkv, hd): the base pass's keys and values of this layer. A path
-    attends to the base keys before t and to its own key at t. Returns
-    the post-attention residual (P, d) and router logits (P, E)."""
-    lp = f32(lp)
+def attend_paths(a: Arch, lp, h, t, kv):
+    """Grouped-query attention half of a block for P single-token paths
+    on float32 parameters ``lp``: h (P, d) each path's block input at its
+    position t (P,); ``kv``, the base pass's keys and values (S, nkv, hd)
+    of this layer. A path attends to the base keys before t and to its
+    own key at t. Returns the post-attention residual (P, d)."""
+    K, V = kv
     P = h.shape[0]
     nh, nkv, hd = a.num_heads, a.num_kv_heads, a.head_dim
     g = nh // nkv
@@ -205,19 +237,62 @@ def path_attention(a: Arch, lp, h, t, K, V):
     o = (jnp.einsum("pngt,tnd->pngd", pr[..., :-1], V,
                     precision=jax.lax.Precision.HIGHEST)
          + pr[..., -1:] * v[:, :, None, :]).reshape(P, nh * hd)
-    u = h + mm(o, at["wo"], "f32")
+    return h + mm(o, at["wo"], "f32")
+
+
+def dense_ffn(a: Arch, p, h, mode):
+    """A dense feed-forward (the MLP's own weights ``p``) on rows h."""
+    if a.act == "swiglu":
+        g = mm(h, p["w_gate"], mode)
+        return mm(g * jax.nn.sigmoid(g) * mm(h, p["w_up"], mode),
+                  p["w_down"], mode)
+    return mm(gelu_tanh(mm(h, p["w_in"], mode)), p["w_out"], mode)
+
+
+def routed(a: Arch, p, h, logits, chosen, mode):
+    """The routed experts' output on rows h (N, d) under the expert choice
+    ``chosen`` (N, E): renormalized softmax weights over every expert."""
+    w = gate_weights(a, logits, chosen)
+    return jnp.einsum("ne,end->nd", w, expert_out(a, p, h, mode),
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+@partial(jax.jit, static_argnames=("a", "mode"))
+def base_layer(a: Arch, lp, x, *, mode):
+    """One default block over a whole sequence x (S, d), causal: the
+    :class:`Layer` ``full`` function, with keys and values as the
+    state."""
+    lp = f32(lp)
+    u, kv = attend_sequence(a, lp, x, mode)
+    h2 = norm(a, lp["norm2"], u)
+    if "moe" not in lp:
+        return u + dense_ffn(a, lp["mlp"], h2, mode), kv, u, None
+    logits = mm(h2, lp["moe"]["router"], mode)
+    y = routed(a, lp["moe"], h2, logits, own_choice(a, logits), mode)
+    return u + y, kv, u, logits
+
+
+@partial(jax.jit, static_argnames=("a",))
+def path_attention(a: Arch, lp, h, t, kv):
+    """Attention half of one default block for P single-token paths (the
+    :class:`Layer` ``attend`` function): the post-attention residual
+    (P, d) and the router logits (P, E), or ``None`` without a router."""
+    lp = f32(lp)
+    u = attend_paths(a, lp, h, t, kv)
+    if "moe" not in lp:
+        return u, None
     return u, mm(norm(a, lp["norm2"], u), lp["moe"]["router"], "f32")
 
 
 @partial(jax.jit, static_argnames=("a",))
-def path_moe(a: Arch, lp, u, logits, chosen):
-    """MoE half of one block for P paths under the given expert choice."""
+def path_ffn(a: Arch, lp, u, logits, chosen):
+    """Feed-forward half of one default block for P paths under the given
+    expert choice (the :class:`Layer` ``ffn`` function)."""
     lp = f32(lp)
     h2 = norm(a, lp["norm2"], u)
-    w = gate_weights(a, logits, chosen)
-    return u + jnp.einsum("ne,end->nd", w, expert_out(a, lp["moe"], h2,
-                                                      "f32"),
-                          precision=jax.lax.Precision.HIGHEST)
+    if "moe" not in lp:
+        return u + dense_ffn(a, lp["mlp"], h2, "f32")
+    return u + routed(a, lp["moe"], h2, logits, chosen, "f32")
 
 
 @partial(jax.jit, static_argnames=("a", "mode"))
@@ -229,8 +304,30 @@ def head(a: Arch, params, x, *, mode):
 
 
 # ------------------------------------------------------------- host logic
-def _layer(params, j):
-    return jax.tree.map(lambda t: t[j], params["blocks"]["pos0"])
+def scanned(params, key: str, j: int):
+    """Layer ``j``'s parameters from a stack the program scans (a leading
+    axis of layers), as a function that takes them when called."""
+    return lambda: jax.tree.map(lambda t: t[j], params["blocks"][key])
+
+
+def default_layers(a: Arch, params) -> List[Layer]:
+    """The model's ``a.num_layers`` layers, block by block through the
+    program's scanned pattern (``blocks/pos0``, ``pos1``, ...), each with
+    the default equations (:func:`base_layer`, :func:`path_attention`,
+    :func:`path_ffn`)."""
+    n = len(params["blocks"])
+    return [default_layer(a, scanned(params, f"pos{j % n}", j // n))
+            for j in range(a.num_layers)]
+
+
+def default_layer(a: Arch, weights: Callable[[], Any]) -> Layer:
+    """A layer of grouped-query attention and the routed MoE layer (or a
+    dense feed-forward, where its parameters hold no router)."""
+    return Layer(weights=weights,
+                 full=lambda lp, x, mode: base_layer(a, lp, x, mode=mode),
+                 attend=lambda lp, h, t, kv: path_attention(a, lp, h, t, kv),
+                 ffn=lambda lp, u, s, c: path_ffn(a, lp, u, s, c),
+                 top_k=a.top_k)
 
 
 def _chunked(fn, n_rows: int, *arrays):
@@ -248,14 +345,13 @@ def _chunked(fn, n_rows: int, *arrays):
     return [np.concatenate(o) for o in zip(*outs)] if outs else None
 
 
-def alternatives(a: Arch, logits: np.ndarray, near_tie: float
+def alternatives(k: int, logits: np.ndarray, near_tie: float
                  ) -> List[Tuple[int, np.ndarray]]:
-    """Near ties of the top-k boundary in router logits (N, E): for each
-    row, every expert choice that swaps one chosen expert for one left
-    out, where both lie within ``near_tie`` standard deviations of the
-    boundary. Returns (row, chosen mask) pairs."""
+    """Near ties of the top-``k`` boundary in router scores (N, E): for
+    each row, every expert choice that swaps one chosen expert for one
+    left out, where both lie within ``near_tie`` standard deviations of
+    the boundary. Returns (row, chosen mask) pairs."""
     out = []
-    k = a.top_k
     order = np.argsort(-logits, axis=1, kind="stable")
     std = logits.std(1)
     for r in range(logits.shape[0]):
@@ -278,14 +374,17 @@ def alternatives(a: Arch, logits: np.ndarray, near_tie: float
 
 def admissible_rows(a: Arch, params, tokens: np.ndarray, first: int, *,
                     near_tie: float, max_flips: int = 2,
-                    pad_to: int = 0):
+                    pad_to: int = 0, layers: Optional[List[Layer]] = None):
     """Reference logit rows for positions ``first .. len(tokens)-1`` of
-    one sequence.
+    one sequence, through ``layers`` (:func:`default_layers` if not
+    given).
 
     Returns ``(rows, row_pos)``: rows (R, vocab) on the host, float32,
     the first ``len(tokens) - first`` of them the base rows in position
     order, then one row per admissible routing path; ``row_pos`` (R,)
     gives each row's index into the compared positions."""
+    if layers is None:
+        layers = default_layers(a, params)
     S = len(tokens)
     S_pad = max(pad_to, S)
     toks = np.zeros(S_pad, np.int32)
@@ -296,19 +395,28 @@ def admissible_rows(a: Arch, params, tokens: np.ndarray, first: int, *,
     p_pos = np.zeros(0, np.int64)
     p_h = np.zeros((0, a.d_model), np.float32)
     p_flips = np.zeros(0, np.int64)
-    for j in range(a.num_layers):
-        lp = _layer(params, j)
-        x_next, K, V, u, logits = base_layer(a, lp, x, mode="f32")
+    for layer in layers:
+        lp = layer.weights()
+        x_next, state, u, logits = layer.full(lp, x, "f32")
         new_pos, new_h, new_flips = [], [], []
-        # existing paths through layer j
-        if len(p_pos):
+        # existing paths through the layer
+        if len(p_pos) and logits is None:       # no router: no branches
+            pu = _chunked(
+                lambda h, t: layer.attend(lp, h, t, state)[0], len(p_pos),
+                p_h, comp[p_pos].astype(np.int32))[0]
+            new_pos.append(p_pos)
+            new_h.append(_chunked(lambda z: layer.ffn(lp, z, None, None),
+                                  len(p_pos), pu)[0])
+            new_flips.append(p_flips)
+        elif len(p_pos):
             pu, plog = _chunked(
-                lambda h, t: path_attention(a, lp, h, t, K, V), len(p_pos),
+                lambda h, t: layer.attend(lp, h, t, state), len(p_pos),
                 p_h, comp[p_pos].astype(np.int32))
             chosen = np.zeros(plog.shape, bool)
-            np.put_along_axis(chosen, np.argsort(-plog, 1)[:, : a.top_k],
+            np.put_along_axis(chosen, np.argsort(-plog, 1)[:, : layer.top_k],
                               True, 1)
-            alts = [(r, m) for r, m in alternatives(a, plog, near_tie)
+            alts = [(r, m) for r, m in alternatives(layer.top_k, plog,
+                                                    near_tie)
                     if p_flips[r] < max_flips][: max(0, MAX_PATHS
                                                      - len(p_pos))]
             rows = np.concatenate([np.arange(len(p_pos)),
@@ -316,24 +424,25 @@ def admissible_rows(a: Arch, params, tokens: np.ndarray, first: int, *,
             masks = np.concatenate([chosen] + [m[None] for _, m in alts])
             n = len(rows)
             new_pos.append(p_pos[rows])
-            new_h.append(_chunked(lambda *z: path_moe(a, lp, *z), n,
+            new_h.append(_chunked(lambda *z: layer.ffn(lp, *z), n,
                                   pu[rows], plog[rows], masks)[0])
             new_flips.append(p_flips[rows]
                              + (np.arange(n) >= len(p_pos)))
         # new paths from the base pass's near ties at compared positions
-        blog = np.asarray(logits)[first:S]
-        room = MAX_PATHS - sum(len(p) for p in new_pos)
-        alts = (alternatives(a, blog, near_tie)[: max(0, room)]
-                if max_flips > 0 else [])
-        if alts:
-            rows = np.asarray([r for r, _ in alts], int)
-            n = len(rows)
-            masks = np.stack([m for _, m in alts])
-            new_pos.append(rows)
-            new_h.append(_chunked(lambda *z: path_moe(a, lp, *z), n,
-                                  np.asarray(u)[rows + first],
-                                  blog[rows], masks)[0])
-            new_flips.append(np.ones(n, np.int64))
+        if logits is not None:
+            blog = np.asarray(logits)[first:S]
+            room = MAX_PATHS - sum(len(p) for p in new_pos)
+            alts = (alternatives(layer.top_k, blog, near_tie)[: max(0, room)]
+                    if max_flips > 0 else [])
+            if alts:
+                rows = np.asarray([r for r, _ in alts], int)
+                n = len(rows)
+                masks = np.stack([m for _, m in alts])
+                new_pos.append(rows)
+                new_h.append(_chunked(lambda *z: layer.ffn(lp, *z), n,
+                                      np.asarray(u)[rows + first],
+                                      blog[rows], masks)[0])
+                new_flips.append(np.ones(n, np.int64))
         if new_pos:
             p_pos = np.concatenate(new_pos)
             p_h = np.concatenate(new_h)
@@ -346,16 +455,20 @@ def admissible_rows(a: Arch, params, tokens: np.ndarray, first: int, *,
 
 
 def control_tokens(a: Arch, params, tokens: np.ndarray, first: int,
-                   *, pad_to: int = 0) -> np.ndarray:
+                   *, pad_to: int = 0,
+                   layers: Optional[List[Layer]] = None) -> np.ndarray:
     """The control: the tokens the float8 model puts first at positions
-    ``first .. len(tokens)-1`` of the same sequence."""
+    ``first .. len(tokens)-1`` of the same sequence, through the same
+    ``layers`` as :func:`admissible_rows`."""
+    if layers is None:
+        layers = default_layers(a, params)
     S = len(tokens)
     S_pad = max(pad_to, S)
     toks = np.zeros(S_pad, np.int32)
     toks[:S] = tokens
     x = embed(a, params, jnp.asarray(toks), mode="fp8")
-    for j in range(a.num_layers):
-        x = base_layer(a, _layer(params, j), x, mode="fp8")[0]
+    for layer in layers:
+        x = layer.full(layer.weights(), x, "fp8")[0]
     top = _chunked(lambda z: jnp.argmax(head(a, params, z, mode="fp8"), -1),
                    S - first, np.asarray(x)[first:S])[0]
     return top.astype(np.int64)

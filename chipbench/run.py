@@ -34,6 +34,7 @@ import time
 PROCESS_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import dataclasses  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
@@ -41,6 +42,7 @@ import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
+from functools import partial  # noqa: E402
 from pathlib import Path  # noqa: E402
 from typing import Dict, List, Optional  # noqa: E402
 
@@ -177,12 +179,29 @@ MODEL_KEYS = {  # configuration key -> how the program's config states it
 }
 
 
+def program_field(mc, path: str):
+    """The field at the dotted ``path`` of the program's model config
+    (``moe.num_shared_experts``), as JSON would state it: a dataclass as
+    the list of its fields, a tuple as a list."""
+    v = mc
+    for name in path.split("."):
+        v = getattr(v, name)
+
+    def plain(x):
+        if dataclasses.is_dataclass(x):
+            x = dataclasses.astuple(x)
+        return [plain(y) for y in x] if isinstance(x, (tuple, list)) else x
+
+    return plain(v)
+
+
 def build(cfg: Dict, seed: int, model_cfg=None):
     """The program under test, with benchmark-made weights. The
     configuration file's ``program`` options (fields of the program's
-    model config, such as tying) are set as it states them."""
-    import dataclasses
-
+    model config, such as tying) are set as it states them. Every
+    ``MODEL_KEYS`` key of the file's ``model``, and every key its
+    ``program_keys`` names (``model`` key -> dotted field of the program's
+    config), must equal the program's."""
     import jax
     import jax.numpy as jnp
 
@@ -196,8 +215,10 @@ def build(cfg: Dict, seed: int, model_cfg=None):
     mc = dataclasses.replace(model_cfg or get_arch(cfg["arch"]),
                              **cfg.get("program", {}))
     m = cfg["model"]
-    wrong = {k: (m[k], f(mc)) for k, f in MODEL_KEYS.items()
-             if m[k] != f(mc)}
+    keys = dict(MODEL_KEYS)
+    for k, path in cfg.get("program_keys", {}).items():
+        keys[k] = partial(program_field, path=path)
+    wrong = {k: (m[k], f(mc)) for k, f in keys.items() if m[k] != f(mc)}
     if m["pos"] == "rope" and mc.rope_theta != m["rope_theta"]:
         wrong["rope_theta"] = (m["rope_theta"], mc.rope_theta)
     if wrong:
@@ -402,12 +423,15 @@ def check(cfg: Dict, params, sample: List[Dict], control: bool,
     """Every sampled request's served tokens against the reference: the
     gap of each served token (``reference/common.py``), and with
     ``control`` the gap of the token the float8 control puts first. Per
-    request: its gaps, and each token's context (the tokens before it)."""
+    request: its gaps, and each token's context (the tokens before it).
+    The configuration's reference module gives ``arch(cfg)`` and, where
+    its layers are not the default ones, ``layers(cfg, params)``."""
     ref = load_module(BENCH / "reference" / f"{cfg['name']}.py",
                       "chipbench_reference")
     from chipbench.reference import common
 
     arch = ref.arch(cfg)
+    layers = ref.layers(cfg, params) if hasattr(ref, "layers") else None
     chk = cfg["check"]
     nt = chk["near_tie"] if near_tie is None else near_tie
     reqs, paths, bad = [], 0, 0
@@ -422,14 +446,16 @@ def check(cfg: Dict, params, sample: List[Dict], control: bool,
         first = len(req.prompt) - 1
         rows, row_pos = common.admissible_rows(
             arch, params, toks, first, near_tie=nt,
-            max_flips=chk["max_flips"], pad_to=cfg["serving"]["max_len"])
+            max_flips=chk["max_flips"], pad_to=cfg["serving"]["max_len"],
+            layers=layers)
         paths += len(row_pos) - len(served)
         one = {"gaps": common.served_gaps(rows, row_pos, served),
                "context": len(req.prompt) + np.arange(len(served)),
                "slot": req.slot}
         if control:
             ctl = common.control_tokens(arch, params, toks, first,
-                                        pad_to=cfg["serving"]["max_len"])
+                                        pad_to=cfg["serving"]["max_len"],
+                                        layers=layers)
             one["control_gaps"] = common.served_gaps(rows, row_pos, ctl)
         reqs.append(one)
         del rows

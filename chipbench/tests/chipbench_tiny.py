@@ -50,3 +50,27 @@ def config(name: str, *, slots: int = 4, max_len: int = 64):
                       d_expert_ff=m["d_expert_ff"]),
         **cfg["program"])
     return cfg, mc
+
+
+FIXTURE = Path(__file__).resolve().parent / "fixture"
+
+
+def fixture():
+    """The dense-shared-moe fixture (``fixture/``): its configuration dict
+    and the program's ModelConfig that serves it."""
+    import repro.configs  # noqa: F401
+    from repro.config import LayerSpec, MoEConfig, get_arch
+
+    cfg = json.loads((FIXTURE / "dense-shared-moe.json").read_text())
+    m = cfg["model"]
+    mc = dataclasses.replace(
+        get_arch(cfg["arch"]), num_layers=m["num_layers"],
+        d_model=m["d_model"], num_heads=m["num_heads"],
+        num_kv_heads=m["num_kv_heads"], d_ff=m["d_dense_ff"],
+        vocab_size=m["vocab_size"], max_seq_len=m["max_seq_len"],
+        pattern=tuple(LayerSpec(*s) for s in m["pattern"]),
+        moe=MoEConfig(num_experts=m["num_experts"], top_k=m["top_k"],
+                      d_expert_ff=m["d_expert_ff"],
+                      num_shared_experts=m["num_shared_experts"],
+                      d_shared_ff=m["d_shared_ff"]))
+    return cfg, mc
