@@ -29,6 +29,7 @@ MIXER_KINDS = (
     "mlstm",         # xLSTM matrix-memory LSTM block
     "slstm",         # xLSTM scalar-memory LSTM block (strictly sequential)
     "shared_attn",   # zamba-style globally shared attention block
+    "mla",           # multi-head latent attention (DeepSeek-V2)
 )
 
 #: Feed-forward kinds.
@@ -65,10 +66,56 @@ class MoEConfig:
     # "expert_parallel" (all_to_all), "expert_parallel_pipelined" (beta chunks)
     dispatch: str = "dense"
     pipeline_degree: int = 1              # beta, used by the pipelined dispatch
+    # top-k weights renormalized over the k (False: the raw softmax probs)
+    norm_topk_prob: bool = True
+    # experts 0 .. experts_held-1 are computed here, of the router's
+    # num_experts (0 -> all): one chip's share under expert parallelism
+    experts_held: int = 0
+
+    def __post_init__(self) -> None:
+        if not 0 <= self.experts_held <= self.num_experts:
+            raise ValueError(f"experts_held={self.experts_held} outside "
+                             f"0..{self.num_experts}")
 
     @property
     def shared_ff(self) -> int:
         return self.d_shared_ff or self.d_expert_ff
+
+    @property
+    def held(self) -> int:
+        """Experts whose weights this layer holds and computes."""
+        return self.experts_held or self.num_experts
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention widths (DeepSeek-V2, no query LoRA).
+
+    Keys and values are decompressed per head from a ``kv_lora_rank``
+    latent; a ``qk_rope_head_dim`` rotary key is shared by the heads. The
+    decode cache holds the normalized latent and the rotated key."""
+
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def latent_dim(self) -> int:
+        """Cached values a token a layer."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+
+@dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rotary scaling (Peng et al., 2023) as DeepSeek-V2 states it."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -105,8 +152,11 @@ class ModelConfig:
     """Full architecture description.
 
     ``pattern`` is the repeating unit of layer specs; ``num_layers`` must be
-    ``len(pattern) * num_blocks``. Stacks are scanned over ``num_blocks`` so
-    compile time is O(len(pattern)), not O(num_layers).
+    ``first_dense_layers + len(pattern) * num_blocks``. Stacks are scanned
+    over ``num_blocks`` so compile time is O(len(pattern)), not
+    O(num_layers). The ``first_dense_layers`` leading layers (DeepSeek's
+    ``first_k_dense_replace``) pair the pattern's first mixer with a dense
+    feed-forward of ``d_ff`` and sit outside the scan.
     """
 
     name: str
@@ -122,9 +172,12 @@ class ModelConfig:
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
     encoder: Optional[EncoderConfig] = None
+    mla: Optional[MLAConfig] = None
+    first_dense_layers: int = 0
     qk_norm: bool = False
     sliding_window: int = 0              # 0 -> disabled; used by "swa" mixers
     rope_theta: float = 10_000.0
+    rope_scaling: Optional[YarnScaling] = None   # the mla mixer only
     pos_embed: str = "rope"              # rope | learned | none
     norm: str = "rmsnorm"                # rmsnorm | layernorm
     activation: str = "swiglu"           # swiglu | gelu
@@ -142,11 +195,28 @@ class ModelConfig:
 
     # ------------------------------------------------------------------ derived
     def __post_init__(self) -> None:
-        if self.num_layers % len(self.pattern) != 0:
+        if (self.num_layers - self.first_dense_layers) % len(self.pattern):
             raise ValueError(
-                f"{self.name}: num_layers={self.num_layers} not divisible by "
-                f"pattern length {len(self.pattern)}"
+                f"{self.name}: num_layers={self.num_layers} less "
+                f"{self.first_dense_layers} leading dense layers not "
+                f"divisible by pattern length {len(self.pattern)}"
             )
+        mixers = {s.mixer for s in self.pattern}
+        if "mla" in mixers:
+            if self.mla is None:
+                raise ValueError(f"{self.name}: mla mixer without MLAConfig")
+            qk = self.mla.qk_nope_head_dim + self.mla.qk_rope_head_dim
+            if self.resolved_head_dim != qk:
+                raise ValueError(f"{self.name}: head_dim must be the mla "
+                                 f"query/key width {qk}")
+        if self.first_dense_layers and (
+                self.pattern[0].mixer not in ("attn", "swa", "mla")
+                or self.encoder is not None):
+            raise ValueError(f"{self.name}: leading dense layers need a "
+                             f"decoder whose first mixer is attention")
+        if self.rope_scaling is not None and mixers != {"mla"}:
+            raise ValueError(f"{self.name}: rope scaling is implemented for "
+                             f"the mla mixer only")
         for spec in self.pattern:
             if spec.ffn == "moe" and self.moe is None:
                 raise ValueError(f"{self.name}: moe layer without MoEConfig")
@@ -157,7 +227,12 @@ class ModelConfig:
 
     @property
     def num_blocks(self) -> int:
-        return self.num_layers // len(self.pattern)
+        return (self.num_layers - self.first_dense_layers) // len(self.pattern)
+
+    @property
+    def lead_spec(self) -> LayerSpec:
+        """The leading dense layers' spec: the pattern's first mixer."""
+        return LayerSpec(self.pattern[0].mixer, "dense")
 
     @property
     def resolved_head_dim(self) -> int:
@@ -178,7 +253,8 @@ class ModelConfig:
 
     @property
     def has_attention(self) -> bool:
-        return any(s.mixer in ("attn", "swa", "shared_attn") for s in self.pattern)
+        return any(s.mixer in ("attn", "swa", "shared_attn", "mla")
+                   for s in self.pattern)
 
     def padded_experts(self, multiple: int) -> int:
         """Experts padded up to ``multiple`` for expert-parallel sharding."""
@@ -199,6 +275,13 @@ class ModelConfig:
         def attn_params() -> int:
             return d * nh * hd + 2 * d * nkv * hd + nh * hd * d + 2 * d  # q,k,v,o + norms
 
+        def mla_params() -> int:
+            a = self.mla
+            assert a is not None
+            return (d * nh * hd + d * a.latent_dim + a.kv_lora_rank
+                    + a.kv_lora_rank * nh * (a.qk_nope_head_dim + a.v_head_dim)
+                    + nh * a.v_head_dim * d + 2 * d)
+
         def ffn_params(ff: int) -> int:
             if self.activation == "swiglu":
                 return 3 * d * ff
@@ -207,6 +290,8 @@ class ModelConfig:
         def mixer_params(kind: str) -> int:
             if kind in ("attn", "swa", "shared_attn"):
                 return attn_params()
+            if kind == "mla":
+                return mla_params()
             s = self.ssm
             assert s is not None
             if kind == "mamba2":
@@ -242,6 +327,8 @@ class ModelConfig:
                 per_unit += m.num_shared_experts * ffn_params(m.shared_ff)
             per_unit += 2 * d                                        # block norms
         total += per_unit * self.num_blocks
+        total += self.first_dense_layers * (
+            mixer_params(self.lead_spec.mixer) + ffn_params(self.d_ff) + 2 * d)
 
         if self.encoder is not None:
             e = self.encoder
@@ -357,9 +444,12 @@ def reduced_config(cfg: ModelConfig, *, num_blocks: int = 2,
     hd = max(16, d_model // heads)
     moe = cfg.moe
     if moe is not None:
+        n_exp = min(moe.num_experts, max_experts)
         moe = dataclasses.replace(
             moe,
-            num_experts=min(moe.num_experts, max_experts),
+            num_experts=n_exp,
+            experts_held=(max(1, moe.experts_held * n_exp // moe.num_experts)
+                          if moe.experts_held else 0),
             top_k=min(moe.top_k, 2),
             d_expert_ff=max(32, int(moe.d_expert_ff * scale)),
             num_shared_experts=min(moe.num_shared_experts, 1),
@@ -374,10 +464,15 @@ def reduced_config(cfg: ModelConfig, *, num_blocks: int = 2,
     if enc is not None:
         enc = dataclasses.replace(enc, num_layers=2, num_heads=heads,
                                   d_ff=2 * d_model, source_len=16)
+    mla = cfg.mla
+    if mla is not None:
+        mla = MLAConfig(kv_lora_rank=64, qk_nope_head_dim=32,
+                        qk_rope_head_dim=16, v_head_dim=32)
+        kv, hd = heads, 48
     return dataclasses.replace(
         cfg,
         name=cfg.name + "-smoke",
-        num_layers=num_blocks * len(cfg.pattern),
+        num_layers=cfg.first_dense_layers + num_blocks * len(cfg.pattern),
         d_model=d_model,
         num_heads=heads,
         num_kv_heads=kv,
@@ -387,6 +482,7 @@ def reduced_config(cfg: ModelConfig, *, num_blocks: int = 2,
         moe=moe,
         ssm=ssm,
         encoder=enc,
+        mla=mla,
         sliding_window=min(cfg.sliding_window, 64) if cfg.sliding_window else 0,
         max_seq_len=256,
         frontend_tokens=min(cfg.frontend_tokens, 16),

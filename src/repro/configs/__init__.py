@@ -18,9 +18,10 @@ from repro.configs import (  # noqa: F401
     paper_bert_moe,
     paper_gpt2_moe,
     paper_bert2bert_moe,
+    deepseek_v2_lite,
 )
 
-#: The ten architectures assigned to this paper, in assignment order.
+#: The architectures assigned to this paper, in assignment order.
 ASSIGNED = (
     "codeqwen1.5-7b",
     "granite-34b",
@@ -32,6 +33,7 @@ ASSIGNED = (
     "granite-moe-3b-a800m",
     "zamba2-7b",
     "whisper-small",
+    "deepseek-v2-lite",
 )
 
 #: The paper's own evaluation models (converted dense->MoE).

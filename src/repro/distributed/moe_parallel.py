@@ -37,7 +37,7 @@ from repro.models.mlp import mlp_forward
 from repro.models.moe import (build_dispatch, build_grouped_dispatch,
                               capacity_for, combine_grouped, combine_tokens,
                               dispatch_grouped, dispatch_tokens, expert_ffn,
-                              grouped_expert_ffn, route)
+                              grouped_expert_ffn, local_experts, route)
 
 
 # β-chunk sizing now lives in the transport-agnostic dispatch substrate
@@ -235,8 +235,8 @@ def expert_parallel_moe_grouped(
             # scatter: every rank sees every rank's sorted chunk + groups
             gx = jax.lax.all_gather(xc, model_axis, axis=0)  # (msize,rows,d)
             gt = jax.lax.all_gather(tc, model_axis, axis=0)  # (msize,tiles)
-            local = gt.reshape(-1) - ridx * e_local
-            owned = (local >= 0) & (local < e_local)
+            local, owned = local_experts(gt.reshape(-1), ridx * e_local,
+                                         e_local)
             lidx = jnp.clip(local, 0, e_local - 1)
             rows = gx.reshape(msize * rows_c, d)
             if use_kernel:

@@ -14,6 +14,16 @@ and writes them stacked over the model's blocks, (num_blocks, B, T,
 n_kv·hd), and views the rows it scores as (B, T, n_kv, hd). GQA is
 computed grouped (q reshaped to (B, S, n_kv, group, hd)) -- no KV head
 repetition.
+
+Multi-head latent attention (``mla``, DeepSeek-V2 without a query LoRA)
+caches a token's normalized latent ``c`` (B, T, kv_lora_rank) and its
+rotated key ``k_pe`` (B, T, qk_rope_head_dim), stacked like K/V in
+decode: two stacks, since a TPU lays a 576-wide stack out T-minor (576
+is no multiple of the 128 lanes) and the decode scan would then copy it
+in and out. Prefill runs the published form, keys and values
+decompressed per head; decode absorbs the key decompression into the
+query and the value decompression after the attention over the latent
+rows, so it reads the latent alone.
 """
 from __future__ import annotations
 
@@ -25,7 +35,8 @@ import jax.numpy as jnp
 
 from repro.config import ModelConfig
 from repro.models.common import (Params, apply_norm, apply_rope, dense_init,
-                                 init_norm, rope_frequencies, split_keys)
+                                 init_norm, rope_frequencies, rope_tables,
+                                 split_keys, yarn_mscale)
 
 NEG_INF = -1e9
 
@@ -58,9 +69,10 @@ def init_attention(key: jax.Array, cfg: ModelConfig, *,
 # Dense attention (short sequences / capture path)
 # ---------------------------------------------------------------------------
 
-def _dense_attend(q, k, v, mask, *, capture: bool = False):
-    """q: (B,N,G,S,D); k,v: (B,N,T,D); mask additive (S,T) or (B,1,1,S,T)."""
-    scale = q.shape[-1] ** -0.5
+def _dense_attend(q, k, v, mask, *, capture: bool = False, scale=None):
+    """q: (B,N,G,S,D); k,v: (B,N,T,D) (v's D may differ); mask additive
+    (S,T) or (B,1,1,S,T); ``scale`` defaults to 1/sqrt(D)."""
+    scale = q.shape[-1] ** -0.5 if scale is None else scale
     scores = jnp.einsum("bngsd,bntd->bngst", q.astype(jnp.float32),
                         k.astype(jnp.float32)) * scale
     scores = scores + mask
@@ -81,8 +93,9 @@ def _dense_attend(q, k, v, mask, *, capture: bool = False):
 
 def _flash_attend(q, k, v, *, causal: bool, window: int, q_offset,
                   kv_valid_len=None, q_chunk: int = 512,
-                  kv_chunk: int = 1024):
-    """Blocked attention. q: (B,N,G,S,D); k,v: (B,N,T,D).
+                  kv_chunk: int = 1024, scale=None):
+    """Blocked attention. q: (B,N,G,S,D); k,v: (B,N,T,D) (v's D may
+    differ); ``scale`` defaults to 1/sqrt(D).
 
     ``q_offset``: absolute position of q[..., 0, :] (scalar, may be traced).
     ``kv_valid_len``: number of valid cache slots for decode — scalar, or
@@ -92,7 +105,8 @@ def _flash_attend(q, k, v, *, causal: bool, window: int, q_offset,
     """
     B, N, G, S, D = q.shape
     T = k.shape[2]
-    scale = D ** -0.5
+    Dv = v.shape[-1]
+    scale = D ** -0.5 if scale is None else scale
     q_chunk = min(q_chunk, S)
     kv_chunk = min(kv_chunk, T)
     nq = -(-S // q_chunk)
@@ -106,7 +120,7 @@ def _flash_attend(q, k, v, *, causal: bool, window: int, q_offset,
     # (nq, B, N, G, Cq, D)
     qc = jnp.moveaxis(q.reshape(B, N, G, nq, q_chunk, D), 3, 0)
     kc = jnp.moveaxis(k.reshape(B, N, nk, kv_chunk, D), 2, 0)
-    vc = jnp.moveaxis(v.reshape(B, N, nk, kv_chunk, D), 2, 0)
+    vc = jnp.moveaxis(v.reshape(B, N, nk, kv_chunk, Dv), 2, 0)
     valid_t = kv_valid_len if kv_valid_len is not None else T
 
     def q_body(qi_q):
@@ -139,14 +153,14 @@ def _flash_attend(q, k, v, *, causal: bool, window: int, q_offset,
 
         m0 = jnp.full((B, N, G, q_chunk), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, N, G, q_chunk), jnp.float32)
-        a0 = jnp.zeros((B, N, G, q_chunk, D), jnp.float32)
+        a0 = jnp.zeros((B, N, G, q_chunk, Dv), jnp.float32)
         (m, l, acc), _ = jax.lax.scan(
             kv_body, (m0, l0, a0), (jnp.arange(nk), kc, vc))
         return acc / jnp.maximum(l, 1e-30)[..., None]
 
     out = jax.lax.map(jax.checkpoint(q_body),
                       (jnp.arange(nq), qc))              # (nq,B,N,G,Cq,D)
-    out = jnp.moveaxis(out, 0, 3).reshape(B, N, G, S_pad, D)
+    out = jnp.moveaxis(out, 0, 3).reshape(B, N, G, S_pad, Dv)
     return out[:, :, :, :S], None
 
 
@@ -389,3 +403,150 @@ def init_cache(cfg: ModelConfig, batch: int, seq_len: int, *,
     T = min(window, seq_len) if window > 0 else seq_len
     shape = (batch, T, nkv * hd)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+
+# ---------------------------------------------------------------------------
+# Multi-head latent attention (DeepSeek-V2)
+# ---------------------------------------------------------------------------
+
+def init_mla(key: jax.Array, cfg: ModelConfig) -> Params:
+    """``wq`` (d, H·(nope+rope)), each head's query [nope | rope];
+    ``wkv_a`` (d, rank+rope) to the latent and the shared rotary key;
+    ``kv_norm`` the latent's RMSNorm; ``wkv_b`` (rank, H·(nope+v)), each
+    head's [key nope | value]; ``wo`` (H·v, d)."""
+    a = cfg.mla
+    assert a is not None
+    d, nh = cfg.d_model, cfg.num_heads
+    ks = split_keys(key, 4)
+    return {
+        "wq": dense_init(ks[0], (d, nh * cfg.resolved_head_dim)),
+        "wkv_a": dense_init(ks[1], (d, a.latent_dim)),
+        "kv_norm": init_norm("rmsnorm", a.kv_lora_rank),
+        "wkv_b": dense_init(ks[2], (a.kv_lora_rank,
+                                    nh * (a.qk_nope_head_dim + a.v_head_dim))),
+        "wo": dense_init(ks[3], (nh * a.v_head_dim, d)),
+    }
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """1/sqrt(nope+rope), times YaRN's ``mscale(factor, mscale_all_dim)``
+    squared where the rope is scaled."""
+    scale = cfg.resolved_head_dim ** -0.5
+    s = cfg.rope_scaling
+    if s is not None and s.mscale_all_dim:
+        scale *= yarn_mscale(s.factor, s.mscale_all_dim) ** 2
+    return scale
+
+
+def _mla_project(params: Params, cfg: ModelConfig, x, positions):
+    """Query (B, S, H, nope+rope) with its rope part rotated, and the
+    cached latent: ``{"c": RMSNorm(c) (B, S, rank), "k_pe": the rotated
+    shared key (B, S, rope)}``."""
+    a = cfg.mla
+    B, S, _ = x.shape
+    nope, r = a.qk_nope_head_dim, a.kv_lora_rank
+    inv, ms = rope_tables(a.qk_rope_head_dim, cfg.rope_theta,
+                          cfg.rope_scaling)
+    q = (x @ params["wq"]).reshape(B, S, cfg.num_heads, -1)
+    q = jnp.concatenate([q[..., :nope],
+                         apply_rope(q[..., nope:], positions, inv, ms)], -1)
+    kv_a = x @ params["wkv_a"]
+    c = apply_norm("rmsnorm", params["kv_norm"], kv_a[..., :r])
+    k_pe = apply_rope(kv_a[..., None, r:], positions, inv, ms)[..., 0, :]
+    return q, {"c": c, "k_pe": k_pe}
+
+
+def mla_forward(params: Params, cfg: ModelConfig, x: jnp.ndarray, *,
+                positions: jnp.ndarray, capture: bool = False,
+                flash_threshold: int = 2048):
+    """Full-sequence causal MLA in the published form: every head's key
+    ``[c W_UK, k_pe]`` and value ``c W_UV`` decompressed from the latent
+    ``c``. Returns (y, the latent cache {"c", "k_pe"}, attn_argmax)."""
+    a = cfg.mla
+    B, S, _ = x.shape
+    nh, nope = cfg.num_heads, a.qk_nope_head_dim
+    q, latent = _mla_project(params, cfg, x, positions)
+    kv = (latent["c"] @ params["wkv_b"]).reshape(B, S, nh, -1)
+    k_pe = jnp.broadcast_to(latent["k_pe"][:, :, None],
+                            (B, S, nh, a.qk_rope_head_dim))
+    k = jnp.concatenate([kv[..., :nope], k_pe], -1)
+    qg = jnp.moveaxis(q[:, :, :, None], 1, 3)            # (B,H,1,S,D)
+    kt = jnp.moveaxis(k, 1, 2)                           # (B,H,S,D)
+    vt = jnp.moveaxis(kv[..., nope:], 1, 2)              # (B,H,S,v)
+    scale = mla_softmax_scale(cfg)
+    if capture or S * S <= flash_threshold * flash_threshold:
+        qpos = positions if positions.ndim else positions[None]
+        kpos = jnp.arange(S)
+        mask = jnp.where(kpos[None, :] <= qpos[:, None], 0.0, NEG_INF)
+        out, argmax = _dense_attend(qg, kt, vt, mask, capture=capture,
+                                    scale=scale)
+    else:
+        out, argmax = _flash_attend(qg, kt, vt, causal=True, window=0,
+                                    q_offset=positions[0], scale=scale)
+    y = jnp.moveaxis(out, 3, 1).reshape(B, S, nh * a.v_head_dim)
+    return y.astype(x.dtype) @ params["wo"], latent, argmax
+
+
+def mla_decode_step(params: Params, cfg: ModelConfig, x: jnp.ndarray,
+                    cache: Dict[str, jnp.ndarray], *, pos, layer,
+                    kv_len: Optional[int] = None, capture: bool = False):
+    """One-token absorbed MLA against the latent stacks ``cache["c"]``
+    (num_blocks, B, T, rank) and ``cache["k_pe"]`` (num_blocks, B, T,
+    rope). Writes each row's latent at ``[layer, row, pos]`` in place
+    (scope ``latent_write``), then scores the query against the first
+    ``kv_len`` latent rows: ``q_nope W_UK^T`` (folded into the latent,
+    scope ``absorb``) against ``c``, and ``q_pe`` against ``k_pe``; the
+    softmax-weighted latent goes out through ``W_UV`` (scope ``absorb``).
+    The absorbed products and the scores run in float32.
+    ``pos``: scalar or (B,) per-row positions. Returns (y, new_cache,
+    attn_argmax)."""
+    a = cfg.mla
+    B = x.shape[0]
+    nh, nope, r = cfg.num_heads, a.qk_nope_head_dim, a.kv_lora_rank
+    T = cache["c"].shape[-2]
+    pos = jnp.asarray(pos)
+    per_slot = pos.ndim == 1
+    q, new = _mla_project(params, cfg, x,
+                          pos[:, None] if per_slot else pos[None])
+    T_att = kv_len if kv_len is not None and kv_len < T else T
+    out, rows = {}, {}
+    for name, stack in cache.items():
+        row = new[name].reshape(B, -1).astype(stack.dtype)
+        with jax.named_scope("latent_write"):
+            if per_slot:
+                stack = stack.at[layer, jnp.arange(B), pos].set(row,
+                                                                mode="drop")
+            else:
+                stack = jax.lax.dynamic_update_slice(
+                    stack, row[None, :, None], (layer, 0, pos, 0))
+        out[name] = stack
+        rows[name] = jax.lax.dynamic_slice(
+            stack, (layer, 0, 0, 0),
+            (1, B, T_att, stack.shape[-1]))[0].astype(jnp.float32)
+    w_b = params["wkv_b"].reshape(r, nh, -1).astype(jnp.float32)
+    with jax.named_scope("absorb"):
+        q_lat = jnp.einsum("bhn,chn->bhc",
+                           q[:, 0, :, :nope].astype(jnp.float32),
+                           w_b[..., :nope])
+    s = (jnp.einsum("bhc,btc->bht", q_lat, rows["c"])
+         + jnp.einsum("bhr,btr->bht", q[:, 0, :, nope:].astype(jnp.float32),
+                      rows["k_pe"])) * mla_softmax_scale(cfg)
+    valid = pos + 1
+    live = jnp.arange(T_att)[None, :] < (valid[:, None] if per_slot
+                                         else valid)
+    s = jnp.where(live[:, None, :], s, NEG_INF)
+    probs = jax.nn.softmax(s, axis=-1)
+    o_lat = jnp.einsum("bht,btc->bhc", probs, rows["c"])
+    with jax.named_scope("absorb"):
+        o = jnp.einsum("bhc,chv->bhv", o_lat, w_b[..., nope:])
+    y = o.reshape(B, 1, nh * a.v_head_dim).astype(x.dtype) @ params["wo"]
+    argmax = (jnp.argmax(probs.sum(1), axis=-1)[:, None] if capture
+              else None)
+    return y, out, argmax
+
+
+def init_mla_cache(cfg: ModelConfig, batch: int, seq_len: int, *,
+                   dtype=jnp.float32) -> Dict[str, jnp.ndarray]:
+    a = cfg.mla
+    return {"c": jnp.zeros((batch, seq_len, a.kv_lora_rank), dtype),
+            "k_pe": jnp.zeros((batch, seq_len, a.qk_rope_head_dim), dtype)}

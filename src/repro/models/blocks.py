@@ -5,8 +5,9 @@ A block = sequence mixer + optional feed-forward, selected by
 their mixer (and companion FFN) weights from a single globally shared
 parameter set passed separately, so scanning over blocks never stacks them.
 
-The attention mixer runs under the name scope ``attention`` and the MoE
-feed-forward under ``moe`` (``jax.named_scope``: the compiled ops'
+The attention mixer (grouped-query or latent) runs under the name scope
+``attention``, the MoE feed-forward under ``moe`` and a dense
+feed-forward under ``dense_ffn`` (``jax.named_scope``: the compiled ops'
 ``op_name`` carries them into the profiler's trace).
 """
 from __future__ import annotations
@@ -19,12 +20,16 @@ import jax.numpy as jnp
 from repro.config import LayerSpec, ModelConfig
 from repro.models import ssm as ssm_mod
 from repro.models.attention import (attention_decode_step, attention_forward,
-                                    init_attention, init_cache)
+                                    init_attention, init_cache, init_mla,
+                                    init_mla_cache, mla_decode_step,
+                                    mla_forward)
 from repro.models.common import Params, init_norm, apply_norm, split_keys
 from repro.models.mlp import init_mlp, mlp_forward
 from repro.models.moe import init_moe, moe_forward
 
 ATTN_MIXERS = ("attn", "swa", "shared_attn")
+# mixers whose decode cache is stacked over blocks and written in place
+CARRIED_MIXERS = ATTN_MIXERS + ("mla",)
 
 
 # ---------------------------------------------------------------------------
@@ -38,6 +43,8 @@ def init_block(key: jax.Array, cfg: ModelConfig, spec: LayerSpec, *,
     p: Params = {"norm1": init_norm(cfg.norm, cfg.d_model)}
     if spec.mixer == "attn" or spec.mixer == "swa":
         p["attn"] = init_attention(ks[0], cfg)
+    elif spec.mixer == "mla":
+        p["attn"] = init_mla(ks[0], cfg)
     elif spec.mixer == "mamba2":
         p["mamba2"] = ssm_mod.init_mamba2(ks[0], cfg)
     elif spec.mixer == "mlstm":
@@ -82,6 +89,8 @@ def init_block_cache(cfg: ModelConfig, spec: LayerSpec, batch: int,
         window = cfg.sliding_window if spec.mixer == "swa" else 0
         cache["attn"] = init_cache(cfg, batch, seq_len, window=window,
                                    dtype=dtype)
+    elif spec.mixer == "mla":
+        cache["attn"] = init_mla_cache(cfg, batch, seq_len, dtype=dtype)
     elif spec.mixer == "mamba2":
         cache["ssm"] = ssm_mod.init_mamba2_cache(cfg, batch, dtype)
     elif spec.mixer == "mlstm":
@@ -135,6 +144,14 @@ def block_forward(
             cache["attn"] = kv
         if capture and argmax is not None:
             cap["attn_argmax"] = argmax
+    elif spec.mixer == "mla":
+        with jax.named_scope("attention"):
+            y, kv, argmax = mla_forward(params["attn"], cfg, h,
+                                        positions=positions, capture=capture)
+        if return_cache:
+            cache["attn"] = kv
+        if capture:
+            cap["attn_argmax"] = argmax
     elif spec.mixer == "mamba2":
         y, st = ssm_mod.mamba2_forward(params["mamba2"], cfg, h)
         if return_cache:
@@ -162,7 +179,8 @@ def block_forward(
     if spec.ffn == "dense":
         h = apply_norm(cfg.norm, params["norm2"], x)
         mlp_p = shared["mlp"] if spec.mixer == "shared_attn" else params["mlp"]
-        x = x + mlp_forward(mlp_p, h, cfg.activation)
+        with jax.named_scope("dense_ffn"):
+            x = x + mlp_forward(mlp_p, h, cfg.activation)
     elif spec.ffn == "moe":
         h = apply_norm(cfg.norm, params["norm2"], x)
         with jax.named_scope("moe"):
@@ -216,8 +234,10 @@ def block_decode_step(
     ``cache`` holds this block's own leaves (``ssm`` states, the ``cross``
     encoder K/V) and, for an attention mixer, under ``attn`` the k/v
     stacks of every block at its unit position, (num_blocks, B, T,
-    n_kv·hd): :func:`attention_decode_step` writes them in place at
-    ``layer`` and ``new_cache["attn"]`` returns the updated stacks.
+    n_kv·hd), or for ``mla`` the latent stacks ``c`` and ``k_pe``:
+    :func:`attention_decode_step` (:func:`mla_decode_step`) writes them in
+    place at ``layer`` and ``new_cache["attn"]`` returns the updated
+    stacks.
 
     Under ``capture``, ``captured`` mirrors :func:`block_forward`'s capture
     dict for the single decoded token: ``attn_argmax`` (B, 1) and the MoE
@@ -244,6 +264,17 @@ def block_decode_step(
                 backend=attn_backend)
         new_cache["attn"] = kv
         if capture and argmax is not None:
+            cap["attn_argmax"] = argmax
+    elif spec.mixer == "mla":
+        if attn_backend != "jnp":
+            raise ValueError("latent attention has no Pallas decode kernel; "
+                             "it needs attn_backend='jnp'")
+        with jax.named_scope("attention"):
+            y, kv, argmax = mla_decode_step(
+                params["attn"], cfg, h, cache["attn"], pos=pos, layer=layer,
+                kv_len=kv_len, capture=capture)
+        new_cache["attn"] = kv
+        if capture:
             cap["attn_argmax"] = argmax
     elif spec.mixer == "mamba2":
         y, st = ssm_mod.mamba2_decode_step(params["mamba2"], cfg, h,
@@ -272,7 +303,8 @@ def block_decode_step(
     if spec.ffn == "dense":
         h = apply_norm(cfg.norm, params["norm2"], x)
         mlp_p = shared["mlp"] if spec.mixer == "shared_attn" else params["mlp"]
-        x = x + mlp_forward(mlp_p, h, cfg.activation)
+        with jax.named_scope("dense_ffn"):
+            x = x + mlp_forward(mlp_p, h, cfg.activation)
     elif spec.ffn == "moe":
         h = apply_norm(cfg.norm, params["norm2"], x)
         with jax.named_scope("moe"):

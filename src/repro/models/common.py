@@ -1,10 +1,12 @@
 """Shared building blocks: initializers, norms, RoPE, embeddings."""
 from __future__ import annotations
 
+import math
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 Params = Dict[str, jnp.ndarray]
 
@@ -66,12 +68,55 @@ def rope_frequencies(head_dim: int, theta: float) -> jnp.ndarray:
                             / head_dim))
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature factor, ``0.1·mscale·ln(factor) + 1``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(dim: int, theta: float, s) -> jnp.ndarray:
+    """YaRN inverse frequencies (``s``: a :class:`repro.config.YarnScaling`),
+    as DeepSeek-V2 computes them: the extrapolated ``theta`` frequencies
+    above the correction range of ``beta_fast``..``beta_slow`` rotations
+    over the original context, the interpolated ones (divided by
+    ``factor``) below it, and a linear ramp between."""
+    def correction_dim(rotations):
+        return (dim * math.log(s.original_max_position_embeddings
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(s.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(s.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    extra = 1.0 / (theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim))
+    inter = extra / np.float32(s.factor)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1)
+    keep = 1.0 - ramp          # 1 where the frequency is extrapolated
+    return jnp.asarray(inter * (1 - keep) + extra * keep, jnp.float32)
+
+
+def rope_tables(dim: int, theta: float, scaling=None
+                ) -> Tuple[jnp.ndarray, float]:
+    """Inverse frequencies of a rotary embedding over ``dim`` dims, and
+    the factor on its cos/sin (1 without ``scaling``)."""
+    if scaling is None:
+        return rope_frequencies(dim, theta), 1.0
+    return (yarn_frequencies(dim, theta, scaling),
+            yarn_mscale(scaling.factor, scaling.mscale)
+            / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+
+
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
-               inv_freq: jnp.ndarray) -> jnp.ndarray:
-    """x: (..., S, H, D); positions: broadcastable to (..., S)."""
+               inv_freq: jnp.ndarray, mscale: float = 1.0) -> jnp.ndarray:
+    """Rotate-half rotary embedding: dim i pairs with i + D/2.
+    x: (..., S, H, D); positions: broadcastable to (..., S); ``mscale``
+    scales cos and sin (YaRN)."""
     angles = positions[..., :, None].astype(jnp.float32) * inv_freq  # (...,S,D/2)
     cos = jnp.cos(angles)[..., :, None, :]
     sin = jnp.sin(angles)[..., :, None, :]
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     y1 = x1 * cos - x2 * sin
     y2 = x2 * cos + x1 * sin

@@ -19,9 +19,22 @@ executors, selected by ``moe_forward(..., executor=...)``:
   (O(N*E*ff), tests/benchmarks only).
 
 The dense and grouped executors run their stages under the name scopes
-``router``, ``dispatch``, ``experts`` (shared experts too) and
-``combine`` (``jax.named_scope``), which the profiler's trace carries in
-each op's ``op_name``.
+``router``, ``dispatch``, ``experts``, ``combine`` and, for always-on
+experts, ``shared`` (``jax.named_scope``), which the profiler's trace
+carries in each op's ``op_name``.
+
+A layer may hold a share of the experts (``MoEConfig.experts_held``, one
+chip's share under expert parallelism): the router keeps its published
+width and top-k, only the pairs routed to held experts are computed, and
+that partial result goes on (the other experts' part belongs to other
+chips). The grouped executor then drops the pairs routed elsewhere,
+sorts the held ones into unpadded groups and runs
+:func:`ragged_expert_ffn` (``jax.lax.ragged_dot``) in place of the
+per-tile gather of :func:`grouped_expert_ffn`, which would hold tiles x
+d x ff weights at once (3.17 GB for a 768-token DeepSeek-V2-Lite
+prefill); dispatch and combine are the same. Layers that hold every
+expert keep the tile gather. ``norm_topk_prob=False`` keeps the raw
+softmax probabilities as the top-k weights.
 
 Every executor emits a shared :class:`RoutingSummary` (per-expert routed
 /kept/dropped counts, drop mask, group offsets) consumed by the serving
@@ -79,6 +92,8 @@ def init_moe(key: jax.Array, cfg: ModelConfig, *,
     d, ff = cfg.d_model, m.d_expert_ff
     ks = split_keys(key, 5)
     p: Params = {"router": dense_init(ks[0], (d, E))}
+    if m.held < m.num_experts:
+        E = m.held              # expert weights of the held share only
     if cfg.activation == "swiglu":
         p["w_gate"] = dense_init(ks[1], (E, d, ff))
         p["w_up"] = dense_init(ks[2], (E, d, ff))
@@ -96,9 +111,27 @@ def init_moe(key: jax.Array, cfg: ModelConfig, *,
 # Routing
 # ---------------------------------------------------------------------------
 
+def gate_topk(probs: jnp.ndarray, m: MoEConfig
+              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The router's top-k of ``probs`` (N, E): weights renormalized over
+    the k, or the raw probabilities where ``m.norm_topk_prob`` is off."""
+    topk_w, topk_idx = jax.lax.top_k(probs, m.top_k)
+    if m.norm_topk_prob:
+        topk_w = topk_w / jnp.maximum(topk_w.sum(-1, keepdims=True), 1e-9)
+    return topk_w, topk_idx
+
+
+def local_experts(idx: jnp.ndarray, first, count: int
+                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Global expert ids ``idx`` as ids among experts ``first ..
+    first+count-1``, and whether each is one of them."""
+    local = idx - first
+    return local, (local >= 0) & (local < count)
+
+
 class RouterOut(NamedTuple):
     topk_idx: jnp.ndarray      # (N, k) int32
-    topk_weight: jnp.ndarray   # (N, k) f32, normalized
+    topk_weight: jnp.ndarray   # (N, k) f32 (see gate_topk)
     probs: jnp.ndarray         # (N, E) f32
     lb_loss: jnp.ndarray       # scalar
     z_loss: jnp.ndarray        # scalar
@@ -113,8 +146,7 @@ def route(router_w: jnp.ndarray, x_flat: jnp.ndarray,
         col = jnp.arange(E_total)
         logits = jnp.where(col < valid_experts, logits, -1e9)
     probs = jax.nn.softmax(logits, axis=-1)
-    topk_w, topk_idx = jax.lax.top_k(probs, m.top_k)
-    topk_w = topk_w / jnp.maximum(topk_w.sum(-1, keepdims=True), 1e-9)
+    topk_w, topk_idx = gate_topk(probs, m)
     # GShard/Switch load-balance loss + router z-loss
     E = probs.shape[-1]
     ohot = jax.nn.one_hot(topk_idx[:, 0], E)           # primary choice
@@ -137,7 +169,7 @@ class FusedRouting(NamedTuple):
     """
 
     topk_idx: jnp.ndarray      # (N, k) int32
-    topk_weight: jnp.ndarray   # (N, k) f32, normalized
+    topk_weight: jnp.ndarray   # (N, k) f32 (see gate_topk)
     pos_in_e: jnp.ndarray      # (N, k) int32 stable within-expert rank
     expert_counts: jnp.ndarray  # (E,) int32 routed pair counts
     lb_loss: jnp.ndarray       # scalar
@@ -160,8 +192,7 @@ def route_fused(router_w: jnp.ndarray, x_flat: jnp.ndarray, m: MoEConfig,
         col = jnp.arange(E)
         logits = jnp.where(col < valid_experts, logits, -1e9)
     probs = jax.nn.softmax(logits, axis=-1)
-    topk_w, topk_idx = jax.lax.top_k(probs, m.top_k)
-    topk_w = topk_w / jnp.maximum(topk_w.sum(-1, keepdims=True), 1e-9)
+    topk_w, topk_idx = gate_topk(probs, m)
     topk_idx = topk_idx.astype(jnp.int32)
     N, k = topk_idx.shape
     # stable within-expert rank via exclusive cumsum of the one-hot pairs
@@ -187,8 +218,13 @@ def route_fused_pallas(router_w: jnp.ndarray, x_flat: jnp.ndarray,
     Integer outputs (indices, ranks, counts) are exact; weights and the
     losses are tolerance-pinned against :func:`route_fused` (the kernel
     reduces the loss statistics tile-by-tile, so float summation order
-    differs).
+    differs). The kernel renormalizes its top-k weights, so it refuses
+    ``norm_topk_prob=False``.
     """
+    if not m.norm_topk_prob:
+        raise ValueError("the Pallas router renormalizes its top-k weights; "
+                         "norm_topk_prob=False needs router_impl 'fused' or "
+                         "'reference'")
     from repro.kernels.router_topk.ops import router_topk_fused_pallas
     E = router_w.shape[-1]
     N = x_flat.shape[0]
@@ -434,10 +470,16 @@ def dispatch_grouped(x_flat: jnp.ndarray, gd: GroupedDispatch) -> jnp.ndarray:
 
 
 def combine_grouped(buf_out: jnp.ndarray, gd: GroupedDispatch,
-                    topk_weight: jnp.ndarray) -> jnp.ndarray:
+                    topk_weight: jnp.ndarray,
+                    held: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Gather every pair's expert output (dropless) and mix by router
-    weight."""
+    weight. Where the (N, k) mask ``held`` marks the pairs computed, the
+    row read for any other lies past the groups, where
+    :func:`ragged_expert_ffn` leaves it undefined: it is zeroed, not only
+    weighted 0."""
     g = buf_out[gd.row_of_pair]                      # (N, k, d)
+    if held is not None:
+        g = jnp.where(held[..., None], g, 0)
     return jnp.einsum("nkd,nk->nd", g, topk_weight.astype(g.dtype))
 
 
@@ -467,6 +509,27 @@ def grouped_expert_ffn(params: Params, buf: jnp.ndarray,
         wd = params["w_out"][tile_expert].astype(jnp.float32)
     out = jnp.einsum("tbf,tfd->tbd", h, wd)
     return out.reshape(R, d).astype(buf.dtype)
+
+
+def ragged_expert_ffn(params: Params, buf: jnp.ndarray,
+                      group_sizes: jnp.ndarray, activation: str
+                      ) -> jnp.ndarray:
+    """Expert FFN over (R, d) rows sorted into contiguous per-expert
+    groups of ``group_sizes`` rows: ``jax.lax.ragged_dot`` against the
+    (E, d, ff) weights, float32 accumulation. Rows past the groups come
+    back undefined (a TPU leaves them unwritten). On a TPU, XLA lowers
+    each product to a Mosaic kernel whose ``op_name`` it sets to
+    ``ragged-dot-none``, so no named scope reaches it in a trace."""
+    def dot(x, w):
+        return jax.lax.ragged_dot(x.astype(w.dtype), w, group_sizes,
+                                  preferred_element_type=jnp.float32)
+
+    if activation == "swiglu":
+        h = jax.nn.silu(dot(buf, params["w_gate"])) * dot(buf, params["w_up"])
+        out = dot(h, params["w_down"])
+    else:
+        out = dot(jax.nn.gelu(dot(buf, params["w_in"])), params["w_out"])
+    return out.astype(buf.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -500,6 +563,23 @@ def _all_experts_out(params: Params, activation: str,
     return jnp.einsum("enf,efd->end", h, params["w_out"])
 
 
+def held_share(r, m: MoEConfig) -> Tuple[FusedRouting, jnp.ndarray]:
+    """Routing ``r`` (global ids over the router's experts) restricted to
+    the experts held, ``0 .. m.held-1``: a :class:`FusedRouting` over them
+    in which pairs routed elsewhere carry the id ``m.held``, weight 0, and
+    no rank or count, and the (N, k) mask of the held pairs."""
+    held = m.held
+    local, owned = local_experts(r.topk_idx, 0, held)
+    idx = jnp.where(owned, local, held).astype(jnp.int32)
+    N, k = idx.shape
+    oh = (idx.reshape(N * k)[:, None]
+          == jnp.arange(held, dtype=jnp.int32)[None, :]).astype(jnp.int32)
+    pos_in_e = ((jnp.cumsum(oh, axis=0) - oh) * oh).sum(-1).reshape(N, k)
+    fr = FusedRouting(idx, jnp.where(owned, r.topk_weight, 0.0), pos_in_e,
+                      oh.sum(0).astype(jnp.int32), r.lb_loss, r.z_loss)
+    return fr, owned
+
+
 def _dropless_summary(counts: jnp.ndarray, drop_mask_shape: Tuple[int, int],
                       group_offsets: jnp.ndarray) -> RoutingSummary:
     return RoutingSummary(
@@ -528,7 +608,9 @@ def moe_forward(params: Params, cfg: ModelConfig, x: jnp.ndarray,
     executor through the same dispatch layouts (integers bit-equal).
     ``expert_ffn_fn`` / ``grouped_ffn_fn`` swap in the Pallas kernels for
     the dense / grouped expert compute respectively. ``aux["routing"]``
-    always carries the executor's :class:`RoutingSummary`.
+    always carries the executor's :class:`RoutingSummary`, over the held
+    experts where the layer holds a share (:func:`held_share`); the
+    captured ``topk_idx`` are global ids.
     """
     m = cfg.moe
     assert m is not None
@@ -552,6 +634,11 @@ def moe_forward(params: Params, cfg: ModelConfig, x: jnp.ndarray,
             r = fr = route_fused(params["router"], x_flat, m,
                                  valid_experts=m.num_experts)
     E = params["router"].shape[-1]
+    w = r.topk_weight
+    owned = None
+    if m.held < m.num_experts:
+        fr, owned = held_share(r, m)
+        E, w = m.held, fr.topk_weight
 
     if executor == "dense":
         C = capacity_for(B * S, m, E)
@@ -563,7 +650,7 @@ def moe_forward(params: Params, cfg: ModelConfig, x: jnp.ndarray,
             fn = expert_ffn_fn or expert_ffn
             buf_out = fn(params, buf, cfg.activation)
         with jax.named_scope("combine"):
-            y = combine_tokens(buf_out, plan, r.topk_weight)
+            y = combine_tokens(buf_out, plan, w)
         counts = plan.expert_counts
         kept = jnp.minimum(counts, C)    # sort-based: first C per expert
         summary = RoutingSummary(
@@ -575,24 +662,38 @@ def moe_forward(params: Params, cfg: ModelConfig, x: jnp.ndarray,
             capacity=jnp.int32(C),
         )
     elif executor == "grouped":
+        # a held share sorts its pairs into unpadded groups for
+        # ragged_expert_ffn and drops the pairs routed elsewhere
+        ragged = owned is not None
+        if ragged and grouped_ffn_fn is not None:
+            raise ValueError("a held share runs ragged_expert_ffn; the "
+                             "grouped kernel hook needs every expert held")
+        rows = 1 if ragged else block_rows
         with jax.named_scope("dispatch"):
-            gd = (build_grouped_dispatch(r.topk_idx, E,
-                                         block_rows=block_rows)
+            gd = (build_grouped_dispatch(r.topk_idx, E, block_rows=rows)
                   if fr is None else
-                  grouped_dispatch_from_fused(fr, E, block_rows=block_rows))
+                  grouped_dispatch_from_fused(fr, E, block_rows=rows))
+            if ragged:
+                gd = gd._replace(row_of_pair=jnp.where(
+                    owned, gd.row_of_pair, gd.num_rows))
             buf = dispatch_grouped(x_flat, gd)
         with jax.named_scope("experts"):
-            fn = grouped_ffn_fn or grouped_expert_ffn
-            buf_out = fn(params, buf, gd.tile_expert, cfg.activation)
+            if ragged:
+                buf_out = ragged_expert_ffn(params, buf, gd.expert_counts,
+                                            cfg.activation)
+            else:
+                fn = grouped_ffn_fn or grouped_expert_ffn
+                buf_out = fn(params, buf, gd.tile_expert, cfg.activation)
         with jax.named_scope("combine"):
-            y = combine_grouped(buf_out, gd, r.topk_weight)
+            y = combine_grouped(buf_out, gd, w, owned)
         summary = _dropless_summary(gd.expert_counts,
                                     (B * S, m.top_k), gd.group_offsets)
     else:  # oracle
         all_out = _all_experts_out(params, cfg.activation, x_flat)
+        idx = r.topk_idx if owned is None else jnp.minimum(fr.topk_idx, E - 1)
         sel = jnp.take_along_axis(
-            jnp.moveaxis(all_out, 0, 1), r.topk_idx[..., None], axis=1)
-        y = jnp.einsum("nkd,nk->nd", sel, r.topk_weight.astype(sel.dtype))
+            jnp.moveaxis(all_out, 0, 1), idx[..., None], axis=1)
+        y = jnp.einsum("nkd,nk->nd", sel, w.astype(sel.dtype))
         counts = (jnp.bincount(r.topk_idx.reshape(-1),
                                length=E).astype(jnp.int32)
                   if fr is None else fr.expert_counts)
@@ -600,7 +701,7 @@ def moe_forward(params: Params, cfg: ModelConfig, x: jnp.ndarray,
                                     jnp.cumsum(counts) - counts)
 
     if m.num_shared_experts > 0:
-        with jax.named_scope("experts"):
+        with jax.named_scope("shared"):
             y = y + mlp_forward(params["shared"], x_flat, cfg.activation)
     aux: Dict[str, jnp.ndarray] = {
         "lb_loss": r.lb_loss * m.router_aux_coef,

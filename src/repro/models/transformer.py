@@ -16,7 +16,11 @@ each device op down to the part of the model it belongs to.
 
 Params are plain nested dicts. Block params are stacked along a leading
 ``num_blocks`` axis; zamba-style shared weights live under ``params["shared"]``
-and are closed over (never stacked).
+and are closed over (never stacked). Leading dense layers
+(``cfg.first_dense_layers``) run before the scan, unrolled, from
+``params["lead"]`` (stacked along their own leading axis); their
+attention cache rows lead the first unit position's stacks, so that one
+stack holds every layer of that mixer.
 """
 from __future__ import annotations
 
@@ -92,6 +96,10 @@ class Model:
                     num_experts=self.num_experts_padded or None)
             )(keys)
         params["blocks"] = blk
+        if cfg.first_dense_layers:
+            keys = jax.random.split(ks[7], cfg.first_dense_layers)
+            params["lead"] = jax.vmap(
+                lambda k: B.init_block(k, cfg, cfg.lead_spec))(keys)
 
         if cfg.encoder is not None:
             e = cfg.encoder
@@ -180,6 +188,18 @@ class Model:
             enc_out = self.encode(params, enc_in)
 
         shared = params["shared"]
+        lead_caches = []
+        for i in range(cfg.first_dense_layers):
+            lead_p = jax.tree.map(lambda a: a[i], params["lead"])
+
+            def lead(h, lead_p=lead_p):
+                return B.block_forward(lead_p, shared, cfg, cfg.lead_spec, h,
+                                       positions=positions,
+                                       return_cache=return_cache)[:2]
+            if self.remat and not (capture or return_cache):
+                lead = jax.checkpoint(lead)
+            x, c = lead(x)
+            lead_caches.append(c)
 
         def body(h, blk_params):
             caches, caps = {}, {}
@@ -199,6 +219,10 @@ class Model:
         if self.remat and not (capture or return_cache):
             body = jax.checkpoint(body)   # activation remat per block
         x, (cache, caps) = jax.lax.scan(body, x, params["blocks"])
+        if lead_caches and return_cache:
+            cache["pos0"] = jax.tree.map(
+                lambda *a: jnp.concatenate([jnp.stack(a[:-1]), a[-1]]),
+                *lead_caches, cache["pos0"])
         with jax.named_scope("head"):
             x = apply_norm(cfg.norm, params["final_norm"], x)
 
@@ -253,19 +277,23 @@ class Model:
         Self-attention K/V (full, windowed ``swa`` and ``shared_attn``
         mixers) are (num_blocks, B, T, n_kv·hd): heads and head dim fused
         into the minor axis, the layout :meth:`decode_step` carries
-        through its layer scan and writes in place; cross-attention K/V
-        share it, recurrent states keep their own shapes."""
+        through its layer scan and writes in place; the latent ``c`` and
+        ``k_pe`` of ``mla`` mixers (num_blocks, B, T, rank | rope) and
+        cross-attention K/V share it, recurrent states keep their own
+        shapes. The first unit position's stacks lead with the leading
+        dense layers' rows: (first_dense_layers + num_blocks, ...)."""
         cfg = self.cfg
         cross_len = cfg.encoder.source_len if cfg.is_encoder_decoder else 0
 
-        def stack(tree):
+        def stack(tree, n):
             return jax.tree.map(
-                lambda a: jnp.broadcast_to(a, (cfg.num_blocks,) + a.shape),
-                tree)
+                lambda a: jnp.broadcast_to(a, (n,) + a.shape), tree)
 
         return {f"pos{p}": stack(B.init_block_cache(
                     cfg, spec, batch, seq_len, cross_len=cross_len,
-                    dtype=dtype))
+                    dtype=dtype),
+                    cfg.num_blocks + (cfg.first_dense_layers if p == 0
+                                      else 0))
                 for p, spec in enumerate(cfg.pattern)}
 
     def prepare_decode_cache(self, cache: Dict[str, Any],
@@ -335,10 +363,12 @@ class Model:
         it). ``attn_backend``: "jnp" | "pallas" decode attention.
 
         The layer scan carries the self-attention K/V stacks,
-        (num_blocks, B, T, n_kv·hd), with the scanned block index, and each
-        block writes its new row into them in place: no layer's cache is
-        sliced out at ``T`` and stacked back. Recurrent states and
-        read-only cross caches are scanned per block."""
+        (num_blocks, B, T, n_kv·hd), or latent stacks, with the scanned
+        block index, and each block writes its new row into them in place:
+        no layer's cache is sliced out at ``T`` and stacked back. Recurrent
+        states and read-only cross caches are scanned per block. Leading
+        dense layers run first, writing the first rows of the first unit
+        position's stacks in place; the scan's blocks write after them."""
         cfg = self.cfg
         executor = moe_executor or self.moe_executor
         router_impl = moe_router_impl or self.moe_router_impl
@@ -354,15 +384,38 @@ class Model:
                     x = x + jax.lax.dynamic_slice_in_dim(
                         params["pos_table"], pos, 1, axis=0)
         shared = params["shared"]
+        block_kw = dict(capture=capture, moe_ffn_fn=self.moe_ffn_fn,
+                        moe_layer_fn=self.moe_layer_fn,
+                        moe_executor=executor,
+                        moe_grouped_fn=self.moe_grouped_fn,
+                        moe_router_impl=router_impl,
+                        dense_threshold=self.decode_dense_threshold,
+                        kv_len=kv_len, attn_backend=backend)
         carried = {f"pos{p}" for p, spec in enumerate(cfg.pattern)
-                   if spec.mixer in B.ATTN_MIXERS}
+                   if spec.mixer in B.CARRIED_MIXERS}
         kv = {n: cache[n]["attn"] for n in carried}
         scanned = {n: {k: a for k, a in c.items() if k != "attn"}
                    for n, c in cache.items()}
+        lead = cfg.first_dense_layers
+        for i in range(lead):
+            x, c, _ = B.block_decode_step(
+                jax.tree.map(lambda a: a[i], params["lead"]), shared, cfg,
+                cfg.lead_spec, x, {"attn": kv["pos0"]}, pos=pos, layer=i,
+                **block_kw)
+            kv["pos0"] = c["attn"]
+
+        blocks = params["blocks"]
 
         def body(carry, xs):
             h, kv = carry
-            layer, blk_params, blk_cache = xs
+            layer, blk_cache = xs
+            # the block's weights, sliced out of the stacks under a scope
+            # of their own: a slice XLA copies out (for a custom call's
+            # operand, as ragged_dot's) is then named in a trace
+            with jax.named_scope("weights"):
+                blk_params = jax.tree.map(
+                    lambda a: jax.lax.dynamic_index_in_dim(
+                        a, layer, keepdims=False), blocks)
             kv, new_caches, caps = dict(kv), {}, {}
             for p, spec in enumerate(cfg.pattern):
                 n = f"pos{p}"
@@ -370,14 +423,8 @@ class Model:
                     else blk_cache[n]
                 h, nc, cap = B.block_decode_step(
                     blk_params[n], shared, cfg, spec, h, c, pos=pos,
-                    layer=layer, capture=capture, cross_valid=cross_valid,
-                    moe_ffn_fn=self.moe_ffn_fn,
-                    moe_layer_fn=self.moe_layer_fn,
-                    moe_executor=executor,
-                    moe_grouped_fn=self.moe_grouped_fn,
-                    moe_router_impl=router_impl,
-                    dense_threshold=self.decode_dense_threshold,
-                    kv_len=kv_len, attn_backend=backend)
+                    layer=layer + lead if lead and p == 0 else layer,
+                    cross_valid=cross_valid, **block_kw)
                 if n in kv:
                     kv[n] = nc.pop("attn")
                 new_caches[n] = nc
@@ -386,7 +433,7 @@ class Model:
 
         (x, kv), (new_cache, caps) = jax.lax.scan(
             body, (x, kv),
-            (jnp.arange(cfg.num_blocks), params["blocks"], scanned))
+            (jnp.arange(cfg.num_blocks), scanned))
         for n in carried:
             new_cache[n]["attn"] = kv[n]
         with jax.named_scope("head"):

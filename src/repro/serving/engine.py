@@ -43,16 +43,20 @@ which ``serving.kv_insert`` and ``serving.device_wait`` nest;
 ``serving.decode`` around building a decode step's inputs and
 dispatching it; ``serving.device_wait`` where the host first waits for a
 program's outputs; ``serving.telemetry`` around the captures' copy to
-the host and the telemetry, predictor and cache hooks; and
-``serving.sample`` around the logits' copy, the argmax and the per-slot
-update. ``kv_rows_read`` and ``kv_rows_live`` count the cache rows the
-decode steps read (every slot up to ``kv_len``) and those of them that
-live requests hold.
+the host and the telemetry, predictor and cache hooks (a step's
+captures are folded in after the next decode step is dispatched, while
+the device runs it); and
+``serving.sample`` around the argmax (on the device, so that only the
+slots' token ids are copied to the host) and the per-slot update.
+``kv_rows_read`` and ``kv_rows_live`` count the cache rows the decode
+steps read (every slot up to ``kv_len``) and those of them that live
+requests hold.
 """
 from __future__ import annotations
 
+import functools
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -77,6 +81,12 @@ from repro.serving.telemetry import ExpertTelemetry
 # original separate-pass / full-buffer path, kept as the equivalence
 # baseline.
 ENGINE_KERNELS = ("fused", "pallas", "reference")
+
+
+def _greedy(logits):
+    """Each row's greedy token: the first of its largest logits, as
+    numpy's argmax picks it (a NaN counts as the largest)."""
+    return jnp.argmax(logits, -1)
 
 
 class ServingEngine:
@@ -121,11 +131,15 @@ class ServingEngine:
                                        weights=tenant_weights)
         self.kv = SlotKVCache(model, self.num_slots, max_len)
         moe = self.cfg.moe
-        self.telemetry: Optional[ExpertTelemetry] = (
-            ExpertTelemetry(self.cfg.num_layers, moe.num_experts,
-                            self.cfg.vocab_size, len(self.cfg.pattern))
+        self._telemetry: Optional[ExpertTelemetry] = (
+            ExpertTelemetry(self.cfg.num_layers, moe.held,
+                            self.cfg.vocab_size, len(self.cfg.pattern),
+                            lead=self.cfg.first_dense_layers)
             if collect_telemetry and moe is not None else None)
-        self._capture = self.telemetry is not None
+        # the served steps' telemetry work not yet done, in serving order
+        # (``_fold_telemetry``)
+        self._telemetry_due: List[Callable[[], None]] = []
+        self._capture = self._telemetry is not None
         if kernels == "pallas" and self._capture:
             raise ValueError(
                 "kernels='pallas' decodes through the flash-decode kernel, "
@@ -134,7 +148,7 @@ class ServingEngine:
         # speculative dispatch: an OnlinePredictor emitting per-layer
         # prewarm hints ahead of each decode step, learning online from
         # the telemetry records the step produces
-        if predictor is not None and self.telemetry is None:
+        if predictor is not None and self._telemetry is None:
             raise ValueError(
                 "a predictor needs expert telemetry (an MoE model and "
                 "collect_telemetry=True) to score and learn from")
@@ -146,16 +160,15 @@ class ServingEngine:
         # before the step, and each step's routed demand is scored
         # against residency (hit / swap / boot) in residency_stats()
         if cache is not None:
-            if self.telemetry is None:
+            if self._telemetry is None:
                 raise ValueError(
                     "an expert-weight cache needs expert telemetry (an "
                     "MoE model and collect_telemetry=True) to track "
                     "residency against routed demand")
-            if (cache.L, cache.E) != (self.cfg.num_layers,
-                                      moe.num_experts):
+            if (cache.L, cache.E) != (self.cfg.num_layers, moe.held):
                 raise ValueError(
                     f"cache geometry {(cache.L, cache.E)} != model "
-                    f"{(self.cfg.num_layers, moe.num_experts)}")
+                    f"{(self.cfg.num_layers, moe.held)}")
         self.cache = cache
         self._n_front = (self.cfg.frontend_tokens
                          if self.cfg.frontend == "vision_stub" else 0)
@@ -206,6 +219,9 @@ class ServingEngine:
         # donates its cache argument: the stored entry cache must survive
         # to serve future hits.
         self._jit_prefix_step = jax.jit(self._prefix_step_impl)
+        # greedy pick of each slot's next token, on the device: only the
+        # slots' ids are copied, not (slots, vocab) float32 logits
+        self._jit_greedy = jax.jit(_greedy)
 
     # ----------------------------------------------------------- jit bodies
     def _prefill_impl(self, params, toks, frontend, enc_tokens, last_idx):
@@ -305,6 +321,66 @@ class ServingEngine:
             out[key] = c
         return out
 
+    # ------------------------------------------------------------ telemetry
+    @property
+    def telemetry(self) -> Optional[ExpertTelemetry]:
+        """The live expert telemetry, with every step served so far
+        folded in."""
+        self._fold_telemetry()
+        return self._telemetry
+
+    def _fold_telemetry(self) -> None:
+        """Do the telemetry work of the steps served so far, in the order
+        they were served. A step leaves its captures on the device and
+        the next decode step folds them in while the device runs it, so
+        that the host's telemetry work leaves the device no idle time."""
+        due, self._telemetry_due = self._telemetry_due, []
+        for fold in due:
+            fold()
+
+    def _record_prefill(self, prompt: np.ndarray, caps: Dict,
+                        caps_len: Optional[int]) -> None:
+        """One admission's routing into the telemetry and the predictor;
+        ``caps`` sliced on the host, or on the device with the prompt's
+        length ``caps_len`` to slice them by."""
+        if caps_len is not None:
+            caps = self._sliced_prefill_captures(jax.device_get(caps),
+                                                 caps_len)
+        tel = self._telemetry
+        mark = tel.num_records
+        tel.record_prefill(prompt[None], caps)
+        if self.predictor is not None:
+            # prefill feeds learning only; hints are a decode-step
+            # concern (prefill routes are observed wholesale)
+            self.predictor.observe_tokens(prompt)
+            self.predictor.update_records(tel.records_since(mark))
+
+    def _record_decode(self, caps: Dict, in_tok: np.ndarray,
+                       in_pos: np.ndarray, seqs: List[np.ndarray],
+                       active: List[int],
+                       hints: Optional[np.ndarray]) -> None:
+        """One decode step's routing into the telemetry, scored against
+        the expert cache's residency and the step's prewarm ``hints``,
+        then into the predictor."""
+        tel = self._telemetry
+        caps_h = jax.device_get(caps)
+        demand_before = (tel.demand.copy()
+                         if hints is not None or self.cache is not None
+                         else None)
+        mark = tel.num_records
+        tel.record_decode(in_tok, in_pos - self._n_front, seqs, caps_h,
+                          active, n_front=self._n_front)
+        if self.cache is not None:
+            # score the step's ACTUAL routing against residency
+            self.cache.serve_demand(tel.demand - demand_before)
+        if hints is not None:
+            # score the hints against what the step actually routed, THEN
+            # learn from the step (hints stay strictly causal)
+            tel.record_prewarm(hints, tel.demand - demand_before)
+            self.predictor.observe_tokens(
+                in_tok[np.asarray(active, np.int64)])
+            self.predictor.update_records(tel.records_since(mark))
+
     def _finish(self, req: Request, reason: str) -> None:
         self.scheduler.finish(req, reason)
         self._finished.append(req)
@@ -335,13 +411,16 @@ class ServingEngine:
         pc_kind, pc_entry = "miss", None
         if self.prefix_cache is not None:
             pc_kind, pc_entry = self.prefix_cache.lookup(req.prompt)
-            if pc_kind == "prefix" and self.telemetry is not None:
+            if pc_kind == "prefix" and self._telemetry is not None:
                 # extension teacher-forces the suffix without capture,
                 # so it cannot replay routing records — with telemetry
                 # on, only exact hits skip the prefill
                 pc_kind, pc_entry = "miss", None
         span.set_metadata(kind=pc_kind)
+        # the admission's captures for the telemetry: sliced on the host,
+        # or on the device with the prompt's length to slice them by
         caps_sliced: Dict[str, Any] = {}
+        caps_len: Optional[int] = None
         if pc_kind == "exact":
             # prefill is deterministic, so the stored prepared cache +
             # last-token logits (and sliced captures, for telemetry
@@ -384,15 +463,18 @@ class ServingEngine:
                 with TraceAnnotation("serving.device_wait"):
                     jax.block_until_ready((last_logits, caps))
                 last_np = np.asarray(last_logits)[0]
-            if self.telemetry is not None:
-                with TraceAnnotation("serving.telemetry"):
-                    caps_h = jax.tree.map(np.asarray, caps)
-                    caps_sliced = self._sliced_prefill_captures(
-                        caps_h, true_len)
+            if self._telemetry is not None:
+                caps_sliced, caps_len = caps, true_len
+                if self.prefix_cache is not None:
+                    # the stored entry replays its captures on a hit
+                    with TraceAnnotation("serving.telemetry"):
+                        caps_sliced = self._sliced_prefill_captures(
+                            jax.device_get(caps), true_len)
+                    caps_len = None
             if self.prefix_cache is not None:
                 self.prefix_cache.put(
                     req.prompt, cache, last_np,
-                    caps_sliced if self.telemetry is not None
+                    caps_sliced if self._telemetry is not None
                     else None)
         self.pos[slot] = s_tot
         if self._enc_dec:
@@ -400,16 +482,9 @@ class ServingEngine:
                 self.enc_valid[slot] = self.cfg.frontend_tokens
             else:
                 self.enc_valid[slot] = len(req.prompt)
-        if self.telemetry is not None:
-            with TraceAnnotation("serving.telemetry"):
-                mark = self.telemetry.num_records
-                self.telemetry.record_prefill(req.prompt[None], caps_sliced)
-                if self.predictor is not None:
-                    # prefill feeds learning only; hints are a decode-
-                    # step concern (prefill routes are observed wholesale)
-                    self.predictor.observe_tokens(req.prompt)
-                    self.predictor.update_records(
-                        self.telemetry.records_since(mark))
+        if self._telemetry is not None:
+            self._telemetry_due.append(functools.partial(
+                self._record_prefill, req.prompt, caps_sliced, caps_len))
         first = int(last_np.argmax())
         req.first_token_time = time.perf_counter()
         if req.max_new_tokens < 1:
@@ -443,6 +518,7 @@ class ServingEngine:
         active = [i for i, r in enumerate(self.scheduler.slots)
                   if r is not None]
         if not active:
+            self._fold_telemetry()
             return False
         in_tok = self.cur_tok.copy()
         in_pos = self.pos.copy()
@@ -451,6 +527,8 @@ class ServingEngine:
         hints = None
         if self.predictor is not None:
             with TraceAnnotation("serving.telemetry"):
+                # the hints learn from every step served before this one
+                self._fold_telemetry()
                 act_tok = in_tok[np.asarray(active, np.int64)]
                 hints = self.predictor.prewarm_hint_matrix(act_tok)
                 self.last_prewarm_hints = hints
@@ -482,34 +560,19 @@ class ServingEngine:
                 self.params, jnp.asarray(in_tok[:, None]), self.kv.cache,
                 jnp.asarray(in_pos), cross_valid, kv_len)
             self.kv.update(cache)
+        with TraceAnnotation("serving.telemetry"):
+            # the steps served before, while the device runs this one
+            self._fold_telemetry()
         with TraceAnnotation("serving.device_wait"):
             jax.block_until_ready((logits, caps))
-        if self.telemetry is not None:
-            with TraceAnnotation("serving.telemetry"):
-                caps_h = jax.tree.map(np.asarray, caps)
-                demand_before = (self.telemetry.demand.copy()
-                                 if hints is not None or self.cache is not None
-                                 else None)
-                mark = self.telemetry.num_records
-                self.telemetry.record_decode(
-                    in_tok, in_pos - self._n_front, self.seqs, caps_h,
-                    active, n_front=self._n_front)
-                if self.cache is not None:
-                    # score the step's ACTUAL routing against residency
-                    self.cache.serve_demand(
-                        self.telemetry.demand - demand_before)
-                if hints is not None:
-                    # score the hints against what the step actually
-                    # routed, THEN learn from the step (hints stay
-                    # strictly causal)
-                    self.telemetry.record_prewarm(
-                        hints, self.telemetry.demand - demand_before)
-                    self.predictor.observe_tokens(
-                        in_tok[np.asarray(active, np.int64)])
-                    self.predictor.update_records(
-                        self.telemetry.records_since(mark))
+        if self._telemetry is not None:
+            # each slot's history before this step's token (the sample
+            # below replaces a slot's array, it never writes into one)
+            self._telemetry_due.append(functools.partial(
+                self._record_decode, caps, in_tok, in_pos, list(self.seqs),
+                active, hints))
         with TraceAnnotation("serving.sample"):
-            nxt = np.asarray(logits).argmax(-1)
+            nxt = np.asarray(self._jit_greedy(logits))
             for i in active:
                 req = self.scheduler.slots[i]
                 assert req is not None
@@ -559,6 +622,7 @@ class ServingEngine:
         if self.cache is None:
             raise ValueError("residency stats need an expert-weight "
                              "cache (ServingEngine(cache=...))")
+        self._fold_telemetry()
         return self.cache.residency_stats()
 
     # ------------------------------------------------------------------- run

@@ -10,7 +10,10 @@ routing-statistics loop of the serverless-MoE systems in PAPERS.md).
 Two products:
 
 * a live ``(num_layers, num_experts)`` routed-token demand matrix, the
-  direct input to ``ServerlessMoERuntime.plan()``;
+  direct input to ``ServerlessMoERuntime.plan()``. Where the model holds
+  a share of its experts (experts ``0 .. num_experts-1`` of the router's
+  ids), the matrix has a column per expert held, and ``routed_away``
+  counts the routed picks of experts held elsewhere;
 * full per-token feature records (f1 token ID, f2 position, f3 attention
   ID, routed experts) in the exact :class:`LayerRecords` format the
   :class:`repro.core.table.KVTable` profiles from, so serving traffic
@@ -18,6 +21,7 @@ Two products:
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
@@ -29,12 +33,19 @@ class ExpertTelemetry:
     """Accumulates routing observations from prefill and decode steps."""
 
     def __init__(self, num_layers: int, num_experts: int, vocab_size: int,
-                 pattern_len: int):
+                 pattern_len: int, lead: int = 0):
+        """``num_experts``: the experts this model computes (held);
+        ``lead``: layers before the scanned pattern, which route nothing
+        (a capture's block ``b`` at unit position ``p`` is layer ``lead +
+        b * pattern_len + p``)."""
         self.num_layers = num_layers
         self.num_experts = num_experts
         self.vocab_size = vocab_size
         self.pattern_len = pattern_len
+        self.lead = lead
         self.demand = np.zeros((num_layers, num_experts))
+        # routed picks of experts not held here (another chip's share)
+        self.routed_away = 0
         # pairs the execution path REFUSED to compute (capacity-buffer
         # drops); identically zero under the dropless grouped executor
         self.drop_counts = np.zeros((num_layers, num_experts))
@@ -65,7 +76,22 @@ class ExpertTelemetry:
             # the real expert count loses nothing
             dropped = np.asarray(summary.dropped)[:, :self.num_experts]
             for b in range(dropped.shape[0]):
-                self.drop_counts[b * self.pattern_len + p] += dropped[b]
+                self.drop_counts[self.lead + b * self.pattern_len + p] += \
+                    dropped[b]
+
+    def _count(self, layers: np.ndarray, experts: np.ndarray) -> None:
+        """Add routed picks (global expert ids) to the demand: ``experts``
+        ``(n, ...)`` holds layer ``layers[j]``'s picks in row ``j``, and
+        all are counted in one pass. A pick of an expert not held counts
+        in ``routed_away``."""
+        experts = np.asarray(experts).reshape(len(layers), -1)
+        held = experts < self.num_experts
+        cell = np.asarray(layers, np.int64)[:, None] * self.num_experts \
+            + experts
+        self.demand += np.bincount(
+            cell[held], minlength=self.demand.size).reshape(
+                self.demand.shape)
+        self.routed_away += int(held.size - held.sum())
 
     def dropped_matrix(self) -> np.ndarray:
         """Cumulative (L, E) pairs dropped by the execution path — the
@@ -80,8 +106,12 @@ class ExpertTelemetry:
         tokens = np.asarray(tokens)
         self._ingest_routing(captures)
         recs = extract_features(tokens, captures, self.pattern_len)
-        for r in recs:
-            np.add.at(self.demand[r.layer], r.experts.ravel(), 1.0)
+        if self.lead:
+            recs = [dataclasses.replace(r, layer=r.layer + self.lead)
+                    for r in recs]
+        if recs:
+            self._count([r.layer for r in recs],
+                        np.stack([r.experts for r in recs]))
         self._records.extend(recs)
         binc = np.bincount(tokens.ravel(), minlength=self.vocab_size)
         self._token_freq += binc
@@ -120,20 +150,21 @@ class ExpertTelemetry:
             topk = np.asarray(cap["topk_idx"])        # (nb, B, 1, k)
             w = np.asarray(cap["topk_weight"])
             nb = topk.shape[0]
-            am = (np.asarray(cap["attn_argmax"])
-                  if "attn_argmax" in cap else None)  # (nb, B, 1)
+            attn_ids = None
+            if "attn_argmax" in cap:
+                am = np.asarray(cap["attn_argmax"])   # (nb, B, 1)
+                # the token each slot attended most, every block at once
+                attn_ids = np.empty((nb, len(act)), np.int64)
+                for j, i in enumerate(act):
+                    seq = seqs[i]
+                    idx = am[:, i, 0].astype(np.int64) - n_front
+                    attn_ids[:, j] = seq[np.clip(idx, 0, len(seq) - 1)]
+            layers = self.lead + np.arange(nb) * self.pattern_len + p
+            self._count(layers, topk[:, act, 0])
             for b in range(nb):
-                layer = b * self.pattern_len + p
+                layer = int(layers[b])
                 experts = topk[b, act, 0]             # (N, k)
-                np.add.at(self.demand[layer], experts.ravel(), 1.0)
-                if am is None:
-                    attn_id = tok                     # self-attention-ID
-                else:
-                    attn_id = np.empty(len(act), np.int64)
-                    for j, i in enumerate(act):
-                        seq = seqs[i]
-                        idx = int(am[b, i, 0]) - n_front
-                        attn_id[j] = seq[np.clip(idx, 0, len(seq) - 1)]
+                attn_id = tok if attn_ids is None else attn_ids[b]
                 self._records.append(LayerRecords(
                     layer=layer,
                     token_id=tok.astype(np.int64),
@@ -210,6 +241,7 @@ class ExpertTelemetry:
 
     def reset(self) -> None:
         self.demand[:] = 0.0
+        self.routed_away = 0
         self.drop_counts[:] = 0.0
         self._token_freq[:] = 0.0
         self.served_freq[:] = 0.0

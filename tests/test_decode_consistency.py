@@ -1,7 +1,8 @@
 """Gold test: sequential one-token decode == full causal forward.
 
-Covers KV-cache attention (full + sliding window), chunked SSD (mamba2),
-chunkwise mLSTM, sequential sLSTM, MoE dispatch, VLM prefix, enc-dec cross
+Covers KV-cache attention (full + sliding window), latent attention with a
+leading dense layer (deepseek-v2-lite), chunked SSD (mamba2), chunkwise
+mLSTM, sequential sLSTM, MoE dispatch, VLM prefix, enc-dec cross
 attention — all through the public prefill/decode API.
 """
 import jax
@@ -13,7 +14,7 @@ from conftest import forward_kwargs, make_inputs, tiny_model
 CAUSAL = ["gpt2-moe", "codeqwen1.5-7b", "gemma3-12b", "xlstm-350m",
           "zamba2-7b", "qwen2-moe-a2.7b", "granite-moe-3b-a800m",
           "llava-next-mistral-7b", "granite-34b", "qwen3-4b",
-          "whisper-small", "bert2bert-moe"]
+          "whisper-small", "bert2bert-moe", "deepseek-v2-lite"]
 
 
 @pytest.mark.parametrize("name", CAUSAL)

@@ -339,7 +339,8 @@ def test_router_fused_matches_jnp_fused_twin():
     ks = jax.random.split(jax.random.PRNGKey(6), 2)
     x = jax.random.normal(ks[0], (N, D))
     w = jax.random.normal(ks[1], (D, E))
-    m = type("M", (), {"num_experts": E, "top_k": k})()
+    m = type("M", (), {"num_experts": E, "top_k": k,
+                       "norm_topk_prob": True})()
     fr = route_fused(w, x, m)
     vals, idx, pos, counts, _, _ = router_topk_fused_pallas(
         x, w, k=k, block_n=64)

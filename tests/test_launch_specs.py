@@ -28,10 +28,10 @@ def test_applicability_matrix():
 
 def test_all_40_pairs_enumerate():
     pairs = [(a, s) for a in ASSIGNED for s in SHAPES]
-    assert len(pairs) == 40
+    assert len(pairs) == 44
     skipped = [(a, s) for a, s in pairs
                if not applicable(get_arch(a), SHAPES[s])[0]]
-    assert len(skipped) == 6          # documented skips
+    assert len(skipped) == 7          # documented skips
 
 
 def test_chunked_ce_matches_plain():

@@ -317,6 +317,96 @@ def test_decode_telemetry_counts(gpt2_moe):
     assert tel.demand.sum() == (n_prompt + n_decode) * cfg.num_layers * k
 
 
+def test_telemetry_folds_a_step_in_while_the_next_one_runs(gpt2_moe):
+    """A decode step's captures are folded in once the next decode step
+    is dispatched, or when the telemetry is read. The records and the
+    demand then equal, in order, those of an engine whose telemetry is
+    read (so folded) after every step, with a request admitted between
+    steps and a slot taken over by the next request."""
+    cfg, model, params = gpt2_moe
+    prompts = _prompts(cfg, [4, 6, 5], seed=4)
+    news = (2, 5, 3)
+    engines = [ServingEngine(model, params, max_len=32, batch_size=2)
+               for _ in range(2)]
+    for eng in engines:
+        for p, n in zip(prompts, news):
+            eng.submit(p, max_new_tokens=n)
+    lazy, eager = engines
+    raw = lazy._telemetry
+    assert lazy.step() and eager.step()
+    eager.telemetry
+    # the step's admissions are in, its decode step is not yet
+    assert raw.prefill_tokens == 10 and raw.decode_tokens == 0
+    assert lazy.step() and eager.step()
+    eager.telemetry
+    assert raw.decode_tokens == 2
+    while lazy.step():
+        eager.step()
+        eager.telemetry
+    assert not eager.step()
+    tel, want = lazy.telemetry, eager.telemetry
+    assert tel.decode_tokens == sum(n - 1 for n in news)
+    np.testing.assert_array_equal(tel.demand, want.demand)
+    assert len(tel._records) == len(want._records)
+    for a, b in zip(tel._records, want._records):
+        assert a.layer == b.layer
+        for f in ("token_id", "position", "attention_id", "experts",
+                  "weights"):
+            np.testing.assert_array_equal(getattr(a, f), getattr(b, f))
+
+
+def test_decode_attention_ids_are_the_attended_tokens():
+    """A decode record's attention ID (f3) of block ``b`` and slot ``i`` is
+    the token of that slot's history at the position it attended most
+    (``attn_argmax`` less the frontend tokens, clipped to the history),
+    for every block, slot, offset and clip."""
+    from repro.serving.telemetry import ExpertTelemetry
+
+    rng = np.random.default_rng(3)
+    nb, slots, k, vocab, n_front = 4, 6, 2, 50, 2
+    seqs = [rng.integers(0, vocab, int(n)) for n in rng.integers(1, 20, slots)]
+    am = rng.integers(-3, 25, (nb, slots, 1))
+    caps = {"pos0": {"topk_idx": rng.integers(0, 8, (nb, slots, 1, k)),
+                     "topk_weight": rng.random((nb, slots, 1, k)),
+                     "attn_argmax": am}}
+    active = [0, 2, 3, 5]
+    tel = ExpertTelemetry(nb, 8, vocab, 1)
+    tel.record_decode(rng.integers(0, vocab, slots), np.zeros(slots, int),
+                      seqs, caps, active, n_front=n_front)
+    assert [r.layer for r in tel._records] == list(range(nb))
+    for b, rec in enumerate(tel._records):
+        want = [seqs[i][min(max(int(am[b, i, 0]) - n_front, 0),
+                            len(seqs[i]) - 1)] for i in active]
+        np.testing.assert_array_equal(rec.attention_id, want)
+        assert rec.attention_id.dtype == np.int64
+
+
+def test_engine_samples_the_argmax_of_its_decode_logits(gpt2_moe):
+    """Each decoded token is the first largest logit of its slot's row, as
+    numpy's argmax of the decode step's logits picks it."""
+    cfg, model, params = gpt2_moe
+    eng = ServingEngine(model, params, max_len=32, batch_size=2)
+    steps = []
+    decode = eng._jit_decode
+
+    def spy(*args):
+        out = decode(*args)
+        steps.append(np.asarray(out[0]))
+        return out
+
+    eng._jit_decode = spy
+    reqs = [eng.submit(p, max_new_tokens=4)
+            for p in _prompts(cfg, [5, 3], seed=9)]
+    eng.run()
+    assert len(steps) == 3
+    for n, logits in enumerate(steps):
+        for req in reqs:
+            assert req.output[1 + n] == int(logits[req.slot].argmax())
+    ties = jnp.asarray([[1.0, 3.0, 3.0], [jnp.nan, 2.0, jnp.nan]])
+    np.testing.assert_array_equal(np.asarray(eng._jit_greedy(ties)),
+                                  np.asarray(ties).argmax(-1))
+
+
 def test_plan_from_telemetry():
     """The runtime re-plans deployment from live serving traffic."""
     from repro.core.runtime import RuntimeConfig, ServerlessMoERuntime

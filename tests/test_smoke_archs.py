@@ -71,5 +71,5 @@ def test_all_assigned_archs_registered():
     for name in ASSIGNED:
         cfg = get_arch(name)
         assert cfg.source, f"{name} missing source citation"
-    assert len(ASSIGNED) == 10
+    assert len(ASSIGNED) == 11
     assert len(set(get_arch(a).arch_type for a in ASSIGNED)) >= 6

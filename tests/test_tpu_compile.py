@@ -11,14 +11,16 @@ runs, so these say nothing about results or speed.
 
 The decode-step cases compile the serving engine's whole decode program
 at a benchmark cell's shapes and read XLA's buffers: the layer scan must
-update the stacked KV cache in place, so no op copies the stack or holds
-one layer's cache at ``max_len``.
+update the stacked KV (or latent) cache in place, so no op copies the
+stack or holds one layer's cache at ``max_len``, and the weights, the
+cache and the program's temporaries must fit the chip's 15.75e9 bytes.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and under several test workers only
 the worker given this file may.
 """
 import dataclasses
+import math
 import re
 
 import jax
@@ -135,15 +137,18 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
 DECODE_CELLS = {
     "gpt2-moe": ("gpt2-moe", {"tie_embeddings": True}, 64),
     "granite-moe-3b-a800m": ("granite-moe-3b-a800m", {}, 24),
+    "dsv2lite-chat-backlog": ("deepseek-v2-lite", {}, 64),
 }
 DECODE_KV_LEN = 1008
 IN_PLACE = ("scatter", "dynamic-update-slice")
-OP = re.compile(r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(")
+OP = re.compile(r"^\s*(?:ROOT )?%(\S+) = (\w+)\[([\d,]*)\]\S* ([\w-]+)\(")
+HLO_TYPES = {"float32": "f32", "bfloat16": "bf16", "int32": "s32"}
 
 
 def _hlo_ops(text):
-    """(name, element count, opcode, fused root opcode or None) of every
-    array-valued op, and no parameter, in a compiled module's text."""
+    """(name, element type, dims, opcode, fused root opcode or None) of
+    every array-valued op, and no parameter, in a compiled module's
+    text."""
     roots, ops, comp = {}, [], None
     for line in text.splitlines():
         if line and not line[0].isspace() and line.rstrip().endswith("{"):
@@ -152,18 +157,66 @@ def _hlo_ops(text):
         m = OP.match(line)
         if not m:
             continue
-        name, dims, opcode = m.groups()
+        name, dt, dims, opcode = m.groups()
         if line.lstrip().startswith("ROOT"):
             roots[comp] = opcode
         if opcode == "parameter":
             continue
-        n = 1
-        for d in filter(None, dims.split(",")):
-            n *= int(d)
         called = re.search(r"calls=%([\w.\-]+)", line)
-        ops.append((name, n, opcode, called and called.group(1)))
-    return [(name, n, opcode, roots.get(c) if c else None)
-            for name, n, opcode, c in ops]
+        shape = tuple(int(d) for d in filter(None, dims.split(",")))
+        ops.append((name, dt, shape, opcode, called and called.group(1)))
+    return [(name, dt, dims, opcode, roots.get(c) if c else None)
+            for name, dt, dims, opcode, c in ops]
+
+
+def _cache_copies(ops, cache, params):
+    """The ops of any element type that hold one layer of a cache stack
+    (by element count, so a reshaped or converted copy counts too), and
+    the ops that move a whole stack other than loop plumbing and in-place
+    updates. An op whose type and dims are those of a weight, or of one
+    layer's slice of a stacked weight, is that weight, not the cache."""
+    stack = {leaf.size for leaf in jax.tree.leaves(cache)}
+    layer = {leaf.size // leaf.shape[0] for leaf in jax.tree.leaves(cache)}
+    weights = {(HLO_TYPES[leaf.dtype.name], shape)
+               for leaf in jax.tree.leaves(params)
+               for shape in (leaf.shape, leaf.shape[1:])}
+    found = []
+    for name, dt, dims, opcode, root in ops:
+        n = math.prod(dims)
+        if (dt, dims) in weights:
+            continue
+        if n in layer:
+            found.append(name)
+        elif n in stack and not (
+                opcode in ("get-tuple-element", "bitcast") + IN_PLACE
+                or (opcode == "fusion" and root in IN_PLACE)):
+            found.append(name)
+    return found
+
+
+@pytest.mark.parametrize("dtype", ["f32", "bf16", "s32"])
+def test_a_layer_sized_op_of_any_type_is_a_cache_copy(dtype):
+    """A one-layer copy of a float32 cache stack is caught whatever its
+    element type (a convert fused into the slice is a copy too), a
+    weight whose element count equals a layer's is not."""
+    cache = {"k": jax.ShapeDtypeStruct((3, 2, 16, 8), F32)}
+    params = {"wo": jax.ShapeDtypeStruct((3, 16, 16), BF16)}
+    text = "\n".join([
+        f"%fused_convert (p: f32[2,16,8]) -> {dtype}[2,16,8] {{",
+        f"  ROOT %convert = {dtype}[2,16,8]{{2,1,0}} convert(%p)",
+        "}",
+        "ENTRY %main (a: f32[3,2,16,8], w: bf16[3,16,16]) -> f32[] {",
+        "  %a = f32[3,2,16,8]{3,2,1,0} parameter(0)",
+        "  %w = bf16[3,16,16]{2,1,0} parameter(1)",
+        f"  %layer_copy = {dtype}[2,16,8]{{2,1,0}} fusion(%a), kind=kLoop, "
+        "calls=%fused_convert",
+        "  %wo_slice = bf16[16,16]{1,0} dynamic-slice(%w)",
+        "  %stack_write = f32[3,2,16,8]{3,2,1,0} dynamic-update-slice(%a)",
+        "  ROOT %r = f32[] constant(0)",
+        "}"])
+    # the fused convert and the fusion that holds it; not the weight
+    assert _cache_copies(_hlo_ops(text), cache, params) == [
+        "convert", "layer_copy"]
 
 
 @pytest.mark.parametrize("cell", sorted(DECODE_CELLS))
@@ -182,19 +235,15 @@ def test_decode_step_updates_the_cache_in_place(cell, one_chip,
         lambda k: model.init_params(k, dtype=BF16), jax.random.PRNGKey(0)))
     cache = on_chip(jax.eval_shape(lambda: model.init_cache(slots,
                                                             MAX_LEN)))
-    stack = {leaf.size for leaf in jax.tree.leaves(cache)}
-    layer = {n // cfg.num_blocks for n in stack}
     compiled = eng._jit_decode.lower(
         params, on_chip(jax.ShapeDtypeStruct((slots, 1), I32)), cache,
         on_chip(jax.ShapeDtypeStruct((slots,), I32)), None,
         DECODE_KV_LEN).compile()
     ops = _hlo_ops(compiled.as_text())
     assert ops
-    # no layer's cache sliced out, or written back, at max_len
-    assert not [op for op in ops if op[1] in layer]
-    # the stack itself only passes through the loop and is updated in place
-    moved = [op for op in ops if op[1] in stack
-             and op[2] not in ("get-tuple-element", "bitcast") + IN_PLACE
-             and not (op[2] == "fusion" and op[3] in IN_PLACE)]
-    assert not moved
-    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+    # no layer's cache sliced out, or written back, at max_len, and the
+    # stack itself only passes through the loop and is updated in place
+    assert not _cache_copies(ops, cache, params)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
